@@ -1,6 +1,7 @@
 """Benchmark harness: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV. Modules:
+Prints ``name,us_per_call,derived`` CSV and exits nonzero when any
+module crashed (its ``<module>.ERROR`` row is still written). Modules:
   bench_fingerprint — paper §IV-C quality table
   bench_tuning      — paper §IV-D Fig. 5 (CherryPick/Arrow +- Perona)
                       + HPO engine (sequential vs vmapped) wall-clock
@@ -108,6 +109,9 @@ def main() -> None:
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.devices}").strip()
 
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_fingerprint, bench_fleet,
                             bench_kernels, bench_optimizer,
                             bench_roofline, bench_tuning,
@@ -229,6 +233,11 @@ def main() -> None:
                     print(f"  {line}", file=sys.stderr)
                 sys.exit(1)
             print("gate: PASS — no confirmed regressions")
+    errors = [n for n, _, _ in rows[1:] if n.endswith(".ERROR")]
+    if errors:
+        print(f"FAIL — {len(errors)} module(s) crashed: "
+              f"{', '.join(errors)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -12,8 +12,8 @@ engine share one policy:
   divisible by the device count;
 - :func:`pad_lanes` / :func:`stack_padded` — build
   the padded (donatable) batch buffers;
-- :func:`axis_specs` / :func:`shard_map_1d` — version-compatible
-  ``shard_map`` wrapping with leading-axis partition specs.
+- :func:`axis_specs` / :func:`shard_map_1d` — ``jax.shard_map``
+  wrapping with leading-axis partition specs.
 
 Every consumer partitions along an *independent-rows* axis only
 (scoring requests, BO lanes), so sharded outputs are bit-identical to
@@ -92,17 +92,9 @@ def axis_specs(axis: str, n_batched: int, n_const: int = 0):
 
 
 def shard_map_1d(fn, mesh, in_specs, out_specs):
-    """Version-compatible ``shard_map``: the stable ``jax.shard_map``
-    when available, the experimental module otherwise; replication
-    checking disabled where supported (the batched buffers are donated
-    and never replicated)."""
-    try:  # stable API (newer jax)
-        from jax import shard_map
-    except ImportError:  # jax <= 0.4/0.5
-        from jax.experimental.shard_map import shard_map
+    """``jax.shard_map`` with the varying-manual-axes check off: the
+    batched buffers are donated and never replicated."""
+    import jax
 
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return shard_map(fn, check_rep=False, **kw)
-    except TypeError:  # newer jax dropped/renamed check_rep
-        return shard_map(fn, **kw)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

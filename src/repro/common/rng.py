@@ -18,10 +18,17 @@ The host-side fingerprint simulators are numpy-based; for them
 from a hashable path (ints and strings), so per-group draws are a pure
 function of ``(seed, round, benchmark_type, machine_type)`` rather than
 a position in one shared stream.
+
+The float64 draws run under :func:`x64_streams`, which also pins
+threefry's original (non-partitionable) bit derivation: JAX 0.5 made
+the partitionable derivation the default, and the seeded realizations
+the tests and recorded results are defined on would otherwise change
+with the installed JAX.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 from typing import Tuple, Union
 
@@ -53,6 +60,17 @@ def stream_key(seed: int, stream_tag: int):
     return np.asarray(jax.random.fold_in(root_key(seed), stream_tag))
 
 
+@contextlib.contextmanager
+def x64_streams():
+    """Float64 on the pinned threefry derivation (see module doc).
+    Wrap every call that traces or lowers a draw of these streams —
+    the flag is read when ``random_bits`` lowers, not when it traces."""
+    import jax
+
+    with jax.enable_x64(), jax.threefry_partitionable(False):
+        yield
+
+
 # --------------------------------------------------------------- device
 def lognormal_noise_row(key_stream, wid, uids, scale):
     """Contention-noise factors ``exp(scale * N(0,1))`` for one
@@ -79,14 +97,13 @@ def lognormal_noise_row(key_stream, wid, uids, scale):
 def lognormal_noise_grid(key_stream, n_workloads: int,
                          uids: np.ndarray, scale: float) -> np.ndarray:
     """The full (n_workloads, len(uids)) contention-noise grid, drawn
-    on host under x64 — row ``w`` is bit-identical to what
-    :func:`lognormal_noise_row` yields for ``wid=w`` inside the
+    on host under :func:`x64_streams` — row ``w`` is bit-identical to
+    what :func:`lognormal_noise_row` yields for ``wid=w`` inside the
     compiled replay program."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with x64_streams():
         wids = jnp.arange(n_workloads)
         grid = jax.jit(jax.vmap(
             lambda w: lognormal_noise_row(key_stream, w, uids, scale)
@@ -101,9 +118,8 @@ def bounded_uniform_grid(key_stream, n_rows: int, lo: np.ndarray,
     row ``r`` depends only on ``r``, never on how many rows exist."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with x64_streams():
         lo = jnp.asarray(lo, jnp.float64)
         hi = jnp.asarray(hi, jnp.float64)
 

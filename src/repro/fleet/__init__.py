@@ -31,14 +31,15 @@ from repro.fleet.faults import (FaultLog, FaultPlan, TelemetryEvent,
                                 inject_faults)
 from repro.fleet.ingest import IngestionDaemon, load_staging, save_staging
 from repro.fleet.service import FleetResult, FleetScoringService
-from repro.fleet.shard import ShardedScorer
+from repro.fleet.shard import ScorerCompileError, ShardedScorer
 from repro.fleet.store import FingerprintStore, atomic_savez
 # last: modelplane leans on repro.obs.regress, which imports
 # repro.fleet.drift — already initialized by this point
 from repro.fleet.modelplane import ModelPlane, ModelRegistry
 
 __all__ = [
-    "FingerprintStore", "ShardedScorer", "FleetScoringService",
+    "FingerprintStore", "ShardedScorer", "ScorerCompileError",
+    "FleetScoringService",
     "EwmaMean", "FleetResult", "NodeDrift", "RollingDrift", "drift_report",
     "degradation_factors", "degrading_nodes", "ewma_series",
     "IngestionDaemon", "save_staging", "load_staging",

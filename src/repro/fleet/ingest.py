@@ -59,6 +59,7 @@ from repro.common.bucketing import next_pow2
 from repro.fingerprint.frame import BenchmarkFrame, concat_frames
 from repro.fleet.drift import RollingDrift, degrading_nodes
 from repro.fleet.faults import TelemetryEvent
+from repro.fleet.shard import ScorerCompileError
 from repro.fleet.store import atomic_savez
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -129,6 +130,7 @@ class IngestionDaemon:
                                          None]] = []
         self._results: Dict[str, List] = {}
         self._closed = False
+        self._fatal: Optional[ScorerCompileError] = None
         self.degraded = False
         self._overload_in_window = 0
         self._clean_windows = 0
@@ -472,6 +474,8 @@ class IngestionDaemon:
                                       "rows": n_rows,
                                       "error": type(e).__name__},
                                 ts=self.now)
+            if isinstance(e, ScorerCompileError):
+                raise  # every later flush would fail the same way
             results = {}
         for hook in self._flush_hooks:
             hook(results, trigger)
@@ -557,13 +561,18 @@ class IngestionDaemon:
             while not self._stop.is_set():
                 now = time.monotonic() - t_start
                 with self._lock:
-                    self.poll_sources(now)
-                    if self._staged_rows >= self.flush_rows:
-                        self._row_trigger_flushes += 1
-                        self._end_window()
-                        self._flush(trigger="rows")
-                    else:
-                        self.advance(now)
+                    try:
+                        self.poll_sources(now)
+                        if self._staged_rows >= self.flush_rows:
+                            self._row_trigger_flushes += 1
+                            self._end_window()
+                            self._flush(trigger="rows")
+                        else:
+                            self.advance(now)
+                    except ScorerCompileError as e:
+                        # stop the stream; close() raises it
+                        self._fatal = e
+                        self._stop.set()
                 self._stop.wait(poll_interval)
 
         self._thread = threading.Thread(target=loop,
@@ -577,7 +586,9 @@ class IngestionDaemon:
         """Crash-safe shutdown: stop the serve thread (if running),
         then either drain staged rows through the scorer or checkpoint
         them (atomic .npz) for :func:`load_staging`. Safe to call
-        twice."""
+        twice. If a :class:`ScorerCompileError` stopped the serve
+        thread, nothing is drained (``checkpoint`` still saves the
+        staged rows) and the error is raised."""
         if self._thread is not None:
             self._stop.set()
             self._thread.join(timeout=10.0)
@@ -586,7 +597,7 @@ class IngestionDaemon:
             if self._closed:
                 return {}
             results = {}
-            if drain and self._staged:
+            if drain and self._staged and self._fatal is None:
                 self._drain_flushes += 1
                 results = self._flush(trigger="drain")
             elif checkpoint is not None and self._staged:
@@ -594,7 +605,9 @@ class IngestionDaemon:
                 self._staged = []
                 self._staged_rows = 0
             self._closed = True
-            return results
+        if self._fatal is not None:
+            raise self._fatal
+        return results
 
     # -------------------------------------------------------------- stats
     def results(self) -> Dict[str, List]:

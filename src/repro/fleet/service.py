@@ -52,7 +52,7 @@ from repro.core.graph_data import chain_structure
 from repro.core.model import PeronaModel
 from repro.core.preprocess import Preprocessor
 from repro.fingerprint.frame import FrameOrRecords, as_frame, concat_frames
-from repro.fleet.shard import ShardedScorer
+from repro.fleet.shard import ScorerCompileError, ShardedScorer
 from repro.fleet.store import FEATURE_KEYS, FingerprintStore
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -351,10 +351,14 @@ class FleetScoringService:
         transient scorer failures (seeded jitter via ``common.rng`` so
         backoff schedules replay deterministically). The stacked numpy
         buffers stay valid across attempts — only the device copies
-        are donated — so a retry re-runs the identical dispatch."""
+        are donated — so a retry re-runs the identical dispatch. A
+        program that fails to compile is raised at once: a retry
+        would only compile it again."""
         for attempt in range(self.dispatch_retries + 1):
             try:
                 return self.scorer.score_stack(params, stack)
+            except ScorerCompileError:
+                raise
             except Exception:
                 if attempt >= self.dispatch_retries:
                     raise

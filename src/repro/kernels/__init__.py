@@ -4,8 +4,10 @@ Each kernel subpackage has: kernel.py (pl.pallas_call + BlockSpec VMEM
 tiling), ops.py (jit'd public wrapper, custom_vjp where training needs
 gradients), ref.py (pure-jnp oracle used by the allclose test sweeps).
 
-Kernels lower for TPU; on this CPU container they are validated in
-interpret mode (pl.pallas_call(..., interpret=True)) against ref.py.
+Kernels lower for TPU; on a CPU backend they run in interpret mode
+(pl.pallas_call(..., interpret=True)), which is how tests/test_kernels.py
+checks them against ref.py. tests/test_tpu_compile.py compiles the
+edge-softmax kernel for a described v5e chip.
 
 Kernels:
   flash_attention — causal / sliding-window / GQA online-softmax attention

@@ -1,4 +1,5 @@
-"""Public wrapper for edge_softmax (pads N to a block multiple).
+"""Public wrapper for edge_softmax: pads N to a block multiple and
+moves the node axis to the lanes for the kernel (``kernel.py``).
 
 The custom VJP saves the forward's attention weights as residuals, so
 the backward pass is three einsums over (g, att, q, k, v) — the softmax
@@ -9,7 +10,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +21,7 @@ BLOCK_N = 512
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return True
+    """Compiled on a TPU backend, interpreted everywhere else."""
     return jax.default_backend() != "tpu"
 
 
@@ -45,9 +44,12 @@ def _agg(q, k, v, mask, scale, interpret):
         k = jnp.pad(k, padw(k))
         v = jnp.pad(v, padw(v))
         mask = jnp.pad(mask, padw(mask))
-    out, att = K.edge_softmax_aggregate(q, k, v, mask, scale=scale,
-                                        block_n=blk, interpret=interpret)
-    return out[:N], att[:N]
+    out, att = K.edge_softmax_aggregate(
+        jnp.transpose(q, (1, 2, 0)), jnp.transpose(k, (1, 2, 3, 0)),
+        jnp.transpose(v, (1, 2, 3, 0)), mask.T, scale=scale,
+        block_n=blk, interpret=interpret)
+    return (jnp.transpose(out, (2, 0, 1))[:N],
+            jnp.transpose(att, (2, 1, 0))[:N])
 
 
 def _fwd(q, k, v, mask, scale, interpret):

@@ -5,15 +5,13 @@ the kernel uses (B, H, S, D). Training gradients use a custom_vjp whose
 backward recomputes with the reference (flash-backward kernels are a TPU
 follow-up; the forward kernel is the inference hot path).
 
-On non-TPU backends the kernel runs in interpret mode (set
-``REPRO_PALLAS_INTERPRET=1`` or pass interpret=True), which is how this
-repo validates it on CPU.
+On non-TPU backends the kernel runs in interpret mode (or pass
+interpret=True), which is how this repo validates it on CPU.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,8 +21,6 @@ from repro.kernels.flash_attention import ref
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return True
     return jax.default_backend() != "tpu"
 
 

@@ -9,7 +9,6 @@ state=None during training; carried state is supported via the oracle.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +18,6 @@ from repro.kernels.mlstm import ref
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return True
     return jax.default_backend() != "tpu"
 
 

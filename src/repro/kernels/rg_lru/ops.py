@@ -3,7 +3,6 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -13,8 +12,6 @@ from repro.kernels.rg_lru import ref
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return True
     return jax.default_backend() != "tpu"
 
 

@@ -32,7 +32,7 @@ import jax
 
 from repro.configs import ARCHS, get_config
 from repro.launch import roofline as rl
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import TARGET_DEVICE_KIND, make_production_mesh
 from repro.launch.steps import lowerable
 from repro.models.config import SHAPES_BY_NAME, shapes_for
 from repro.models.model_zoo import build_model
@@ -59,7 +59,7 @@ def _compile(cfg, shape, mesh, layout: str = "2d", donate: bool = False):
 
 
 def _costs(compiled):
-    ca = rl.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     coll = rl.collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -84,6 +84,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
     record = {
         "arch": arch, "shape": shape_name, "mesh": mesh_kind,
         "mesh_shape": dict(mesh.shape), "tag": tag, "status": "ok",
+        "target_device_kind": TARGET_DEVICE_KIND,
         "layout": layout, "donate": donate,
         "overrides": {k: str(v) for k, v in (overrides or {}).items()},
     }
@@ -118,7 +119,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
                         + (n - p2) * (k2["coll"][op] - k1["coll"][op]))
                 for op in k2["coll"]
             }
-            terms = rl.roofline_terms(flops, bytes_, sum(coll.values()))
+            terms = rl.roofline_terms(flops, bytes_, sum(coll.values()),
+                                      device_kind=TARGET_DEVICE_KIND)
             n_chips = 1
             for v in mesh.shape.values():
                 n_chips *= v

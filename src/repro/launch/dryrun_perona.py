@@ -22,7 +22,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.model import PeronaConfig, PeronaModel
 from repro.launch import roofline as rl
-from repro.launch.mesh import data_axes, make_production_mesh
+from repro.launch.mesh import (TARGET_DEVICE_KIND, data_axes,
+                               make_production_mesh)
 from repro.optim.adamw import AdamW
 
 
@@ -77,7 +78,8 @@ def main() -> None:
         return params, state, loss
 
     record = {"arch": "perona-fingerprint", "shape": f"fleet_{FLEET_N}",
-              "mesh": args.mesh, "status": "ok"}
+              "mesh": args.mesh, "status": "ok",
+              "target_device_kind": TARGET_DEVICE_KIND}
     try:
         t0 = time.time()
         with mesh:
@@ -86,7 +88,7 @@ def main() -> None:
                 in_shardings=(pshard, oshard, bshard)).lower(
                     aparams, astate, batch)
             compiled = lowered.compile()
-        ca = rl.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         coll = rl.collective_bytes(compiled.as_text())
         flops = float(ca.get("flops", 0.0))
         record.update({
@@ -96,7 +98,7 @@ def main() -> None:
             "collective_bytes_per_device": coll,
             "roofline": rl.roofline_terms(
                 flops, float(ca.get("bytes accessed", 0.0)),
-                sum(coll.values())),
+                sum(coll.values()), device_kind=TARGET_DEVICE_KIND),
         })
     except Exception as e:  # noqa: BLE001
         record["status"] = "error"

@@ -14,15 +14,18 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
+#: The chip the production meshes stand for (``jax.Device.device_kind``
+#: of a TPU v5e): the dry-runs compile on placeholder host devices and
+#: price their roofline terms with this kind's peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _make_mesh(shape, axes, devices):
-    """jax.make_mesh across jax versions: ``axis_types`` exists only on
-    newer releases (and older ones default to Auto anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagation
+    decides placements; this JAX defaults new meshes to ``Explicit``)."""
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,10 +1,10 @@
 """Roofline-term extraction from a compiled dry-run artifact.
 
-Three terms (seconds), per device, TPU v5e constants:
+Three terms (seconds), per device, from the peaks of the target chip:
 
-  compute    = HLO_FLOPs / peak_FLOPs            (197 TFLOP/s bf16)
-  memory     = HLO_bytes / HBM_bw                (819 GB/s)
-  collective = collective_bytes / link_bw        (~50 GB/s per ICI link)
+  compute    = HLO_FLOPs / peak_flops
+  memory     = HLO_bytes / hbm_bw
+  collective = collective_bytes / link_bw
 
 ``cost_analysis`` of the partitioned module reports per-device FLOPs and
 bytes. Collective bytes are parsed from the post-optimization HLO text:
@@ -15,11 +15,26 @@ all-to-all / collective-permute ops (per-device shard shapes).
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Tuple
+from typing import Dict
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-LINK_BW = 50e9  # bytes/s per ICI link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s of inter-chip interconnect (4 links of
+#: ~50 GB/s each).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table row of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -67,19 +82,6 @@ def _group_size(line: str) -> int:
     return 2
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on new jax and a
-    one-element list of dicts on older releases — normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        out: Dict[str, float] = {}
-        for entry in ca:
-            for k, v in entry.items():
-                out[k] = out.get(k, 0.0) + float(v)
-        return out
-    return dict(ca)
-
-
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
     """Per-opcode *wire* bytes per device (ring-model) of collectives.
 
@@ -117,10 +119,12 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 
 def roofline_terms(flops: float, bytes_accessed: float,
-                   coll_bytes: int) -> Dict[str, float]:
-    compute = flops / PEAK_FLOPS
-    memory = bytes_accessed / HBM_BW
-    collective = coll_bytes / LINK_BW
+                   coll_bytes: int, *, device_kind: str
+                   ) -> Dict[str, float]:
+    pk = peaks(device_kind)
+    compute = flops / pk["flops"]
+    memory = bytes_accessed / pk["hbm_bw"]
+    collective = coll_bytes / pk["link_bw"]
     terms = {"compute_s": compute, "memory_s": memory,
              "collective_s": collective}
     dom = max(terms, key=terms.get)

@@ -73,7 +73,7 @@ import argparse
 import dataclasses
 import threading
 import time
-from typing import List, Optional
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -184,9 +184,33 @@ def merge_cache_slot(cache_old, cache_new, slot: int):
     return out
 
 
-def _trained_perona(machines, runs_per_type: int, seed: int):
-    """Acquire + fit + train one small Perona model for the serving
-    loops (shared by --fingerprint and --fleet)."""
+class TrainedPerona(NamedTuple):
+    """A seeded acquisition and the Perona model trained on it."""
+
+    runner: object  # fingerprint.runner.SuiteRunner
+    frame: object  # fingerprint.frame.BenchmarkFrame (the acquisition)
+    pre: object  # core.preprocess.Preprocessor fitted on ``frame``
+    model: object  # core.model.PeronaModel
+    params: dict
+    history: list  # per-epoch {"epoch", "train_loss"}
+
+
+#: Nodes whose seeded acquisition trains the fleet loops' model. The
+#: full-batch trainer's pairwise losses hold N x N matrices: 1024
+#: nodes' 61,440 executions would need 21 GB of HBM, 256 nodes' 15,360
+#: need 1.5 GB of a TPU v5e's 16 GB.
+TRAIN_NODES = 256
+
+
+def fleet_machines(nodes: int) -> dict:
+    """The served fleet: ``nodes`` homogeneous e2-medium nodes."""
+    return {f"fleet-{i}": "e2-medium" for i in range(nodes)}
+
+
+def trained_perona(machines, runs_per_type: int,
+                   seed: int) -> TrainedPerona:
+    """Acquire + fit + train one Perona model for the serving loops
+    (shared by --fingerprint, --fleet and --daemon)."""
     from repro.core.graph_data import build_graphs
     from repro.core.model import PeronaConfig, PeronaModel
     from repro.core.preprocess import Preprocessor
@@ -201,8 +225,11 @@ def _trained_perona(machines, runs_per_type: int, seed: int):
     cfg = PeronaConfig(feature_dim=pre.feature_dim,
                        edge_dim=batch.edge.shape[-1])
     model = PeronaModel(cfg)
-    res = train_perona(model, batch, epochs=40, seed=seed)
-    return runner, frame, pre, model, res.params
+    # 40 epochs left the anomaly head near its prior (every node
+    # scored ~0.4, a degraded one included); 200 separate them
+    res = train_perona(model, batch, epochs=200, seed=seed)
+    return TrainedPerona(runner, frame, pre, model, res.params,
+                         res.history)
 
 
 def serve_fingerprints(rounds: int, runs_per_type: int = 2,
@@ -214,7 +241,7 @@ def serve_fingerprints(rounds: int, runs_per_type: int = 2,
     from repro.runtime.watchdog import PeronaWatchdog
 
     machines = {f"serve-{i}": "e2-medium" for i in range(3)}
-    runner, frame, pre, model, params = _trained_perona(
+    runner, frame, pre, model, params, _ = trained_perona(
         machines, runs_per_type=40, seed=seed)
 
     service = FleetScoringService(model, params, pre,
@@ -243,9 +270,10 @@ def serve_fleet(nodes: int = 16, rounds: int = 10,
     the sharded scoring path, with store-backed drift analytics."""
     from repro.fleet import FleetScoringService, drift_report
 
-    machines = {f"fleet-{i}": "e2-medium" for i in range(nodes)}
-    runner, frame, pre, model, params = _trained_perona(
-        machines, runs_per_type=10, seed=seed)
+    machines = fleet_machines(nodes)
+    runner, frame, pre, model, params, _ = trained_perona(
+        fleet_machines(min(nodes, TRAIN_NODES)), runs_per_type=10,
+        seed=seed)
 
     service = FleetScoringService(model, params, pre,
                                   context_per_chain=16)
@@ -277,14 +305,18 @@ def serve_daemon(nodes: int = 6, rounds: int = 12,
     ``modelplane=True`` the run exercises the full model lifecycle on
     the live stream: canary + hot-promote of an identical candidate,
     then a forced promote of a NaN-poisoned candidate that the health
-    watch rolls back automatically."""
+    watch rolls back automatically. The model trains on the first
+    :data:`TRAIN_NODES` nodes' acquisition, which also seeds the
+    store's history."""
     from repro.fleet import (FaultPlan, FleetScoringService,
                              IngestionDaemon, ModelPlane,
                              fleet_telemetry, inject_faults)
 
-    machines = {f"fleet-{i}": "e2-medium" for i in range(nodes)}
-    _, frame, pre, model, params = _trained_perona(
-        machines, runs_per_type=10, seed=seed)
+    machines = fleet_machines(nodes)
+    trained = trained_perona(
+        fleet_machines(min(nodes, TRAIN_NODES)),
+        runs_per_type=10, seed=seed)
+    _, frame, pre, model, params, _ = trained
 
     service = FleetScoringService(model, params, pre,
                                   context_per_chain=16)
@@ -334,6 +366,8 @@ def serve_daemon(nodes: int = 6, rounds: int = 12,
             "faults": fault_counts,
             "degraded_node": degraded_node if faults else None,
             "flagged": daemon.flagged_nodes(),
+            "daemon": daemon,
+            "trained": trained,
             "modelplane": None if plane is None else plane.status(),
             "registry": registry_dir,
             "versions": (None if plane is None
@@ -417,11 +451,14 @@ def main() -> None:
     ap.add_argument("--metrics-interval", type=float, default=10.0,
                     help="seconds between --metrics dumps")
     args = ap.parse_args()
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     dumper = (_start_metrics_dumper(args.metrics_interval)
               if args.metrics else None)
     try:
-        tracer = _run(args)
+        tracer, failure = _run(args)
     finally:
         if dumper is not None:
             dumper.set()
@@ -431,6 +468,8 @@ def main() -> None:
                 print(f"[metrics final]\n{text}", flush=True)
     if args.timeline:
         _export_timeline(args.timeline, tracer=tracer)
+    if failure:
+        raise SystemExit(failure)
 
 
 def _modelplane_cmd(args) -> None:
@@ -477,12 +516,13 @@ def _modelplane_cmd(args) -> None:
               f"v{reg.incumbent}")
 
 
-def _run(args) -> Optional[obs.Tracer]:
+def _run(args) -> Tuple[Optional[obs.Tracer], Optional[str]]:
     """Dispatch one serving mode; returns the tracer whose recording
-    ``--timeline`` should export (None -> the process-wide tracer)."""
+    ``--timeline`` should export (None -> the process-wide tracer) and
+    the failure the process must exit with (None on success)."""
     if args.modelplane_cmd:
         _modelplane_cmd(args)
-        return None
+        return None, None
 
     if args.fingerprint:
         out = serve_fingerprints(args.rounds, seed=args.seed)
@@ -490,7 +530,7 @@ def _run(args) -> Optional[obs.Tracer]:
               f"executions, {out['seconds']:.2f}s "
               f"({out['scored'] / max(out['seconds'], 1e-9):.0f} exec/s), "
               f"{out['traces']} compiles, excluded={out['excluded']}")
-        return None
+        return None, None
 
     if args.daemon:
         out = serve_daemon(args.nodes, args.rounds, seed=args.seed,
@@ -514,7 +554,9 @@ def _run(args) -> Optional[obs.Tracer]:
               f"{st['degraded_flushes']} degraded flushes "
               f"({st['degrade_unscored_rows']} sampled-out rows); "
               f"dedup dropped {st['duplicates_dropped']}; "
-              f"quarantined {svc['quarantined_rows']} rows")
+              f"quarantined {svc['quarantined_rows']} rows; "
+              f"{st['flush_failures']} flush failures, "
+              f"{st['scorer_retries']} scorer retries")
         if out["faults"] is not None:
             print(f"[serve-daemon] injected faults: {out['faults']}; "
                   f"degraded node {out['degraded_node']} -> "
@@ -533,7 +575,11 @@ def _run(args) -> Optional[obs.Tracer]:
             for e in out["versions"]:
                 print(f"[modelplane]   v{e['version']} "
                       f"{e['status']} ({e['source']})")
-        return out["tracer"]
+        failure = None
+        if st["flush_failures"]:
+            failure = (f"[serve-daemon] FAILED: {st['flush_failures']} "
+                       "flush(es) lost their scores")
+        return out["tracer"], failure
 
     if args.fleet:
         out = serve_fleet(args.nodes, args.rounds, seed=args.seed)
@@ -545,7 +591,7 @@ def _run(args) -> Optional[obs.Tracer]:
               f"{s['requests_per_s']:.0f} req/s; "
               f"drift tracked for {out['drift_nodes']} nodes, "
               f"worst={out['worst_node']}")
-        return None
+        return None, None
 
     cfg = get_config(args.arch)
     if args.scale == "small":
@@ -572,7 +618,7 @@ def _run(args) -> Optional[obs.Tracer]:
     print(f"[serve] {len(out['completed'])} requests, {n_tokens} tokens, "
           f"{out['decode_steps']} decode steps, {dt:.1f}s "
           f"({n_tokens/max(dt,1e-9):.1f} tok/s)")
-    return None
+    return None, None
 
 
 if __name__ == "__main__":

@@ -80,6 +80,24 @@ def _kernel(a: jnp.ndarray, b: jnp.ndarray,
     return jnp.exp(-0.5 * sq)
 
 
+def cholesky(k: jnp.ndarray) -> jnp.ndarray:
+    """Lower Cholesky factor of one small SPD matrix, column by column.
+
+    Used in place of ``jnp.linalg.cholesky``, which the TPU compiler
+    refuses in float64 inside any partitioned program (``shard_map`` or
+    automatic sharding: "A tuple parameter that is being flattened
+    shouldn't have frontend attributes") — the sharded replay is one.
+    ``k`` is P x P with P the pow2 observation-slot count, so the
+    unrolled loop stays short."""
+    p = k.shape[-1]
+    chol = jnp.zeros_like(k)
+    for j in range(p):
+        d = jnp.sqrt(k[j, j] - jnp.sum(chol[j, :j] ** 2))
+        col = (k[j + 1:, j] - chol[j + 1:, :j] @ chol[j, :j]) / d
+        chol = chol.at[j, j].set(d).at[j + 1:, j].set(col)
+    return chol
+
+
 def gp_fit(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray,
            noise: float = 1e-3,
            median_rows: Optional[int] = None) -> GPState:
@@ -101,7 +119,7 @@ def gp_fit(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray,
     pmask = mask[:, None] & mask[None, :]
     k = jnp.where(pmask, _kernel(x, x, scales), 0.0)
     k = k + jnp.diag(jnp.where(mask, noise, 1.0 + noise))
-    chol = jnp.linalg.cholesky(k)
+    chol = cholesky(k)
     alpha = cho_solve((chol, True), yn[:, None])[:, 0]
     return GPState(chol=chol, alpha=alpha, x=x, mask=mask,
                    scales=scales, y_mean=y_mean, y_std=y_std)

@@ -29,7 +29,7 @@ produces the overlap instead: per-device worker threads run this same
 entry point while the main thread builds the next lane block's
 tables.
 
-All math runs in float64 (``jax.experimental.enable_x64`` around the
+All math runs in float64 (``jax.enable_x64`` around the
 dispatch) so batched lanes reproduce the sequential scipy traces
 bit-for-bit on identical seeds: same evaluated configs, same
 best-valid-cost curves (see tests/test_optimizer.py).
@@ -329,6 +329,27 @@ def _seeded_replay_fn(cfg: ReplayConfig, lanes: int, slots: int,
     return jax.jit(run, donate_argnums=(0,))
 
 
+def _placement(device, devs):
+    """Host-to-device placement of a dispatch's inputs: ``(lane_put,
+    grid_put)`` for lane-partitioned and replicated arrays. With a
+    device mesh each array goes straight to its sharding, so nothing
+    is staged on the first device; otherwise everything goes to
+    ``device`` (or the default device)."""
+    import jax
+
+    if devs is None:
+        def put(a):
+            return jax.device_put(a, device)
+        return put, put
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    mesh = build_mesh("lanes", devs)
+    lanes, grid = NamedSharding(mesh, P("lanes")), NamedSharding(mesh, P())
+    return (lambda a: jax.device_put(a, lanes),
+            lambda a: jax.device_put(a, grid))
+
+
 @dataclasses.dataclass
 class PendingReplay:
     """A dispatched-but-not-fetched replay: ``sel``/``count`` may still
@@ -365,7 +386,6 @@ def replay_async(tables: LaneTables,
     ``scenarios.replay_pipelined``).
     """
     import jax
-    from jax.experimental import enable_x64
 
     cfg = ReplayConfig() if cfg is None else cfg
     if devices is not None and device is not None:
@@ -397,13 +417,9 @@ def replay_async(tables: LaneTables,
     from repro.serving.engine import silence_unusable_donation
 
     fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, devs)
+    to_dev, _ = _placement(device, devs)
 
-    def to_dev(a):
-        if device is not None:
-            return jax.device_put(a, device)
-        return jax.numpy.asarray(a)
-
-    with enable_x64(), silence_unusable_donation():
+    with jax.enable_x64(), silence_unusable_donation():
         # copy=False: lane_tables already builds f64 columns, so the
         # dtype casts are no-ops for the common path
         jnp_tables = tuple(
@@ -457,8 +473,7 @@ def replay_seeded_async(spec: SeededLaneSpec,
 
     The condition axis is pow2-padded so matrices with different
     condition counts reuse one compiled program."""
-    import jax
-    from jax.experimental import enable_x64
+    from repro.common.rng import x64_streams
 
     cfg = ReplayConfig() if cfg is None else cfg
     if devices is not None and device is not None:
@@ -501,13 +516,9 @@ def replay_seeded_async(spec: SeededLaneSpec,
     fn = _seeded_replay_fn(cfg, lanes, slots, n_cand, base_dim, rounds,
                            n_workloads, n_conds,
                            float(spec.noise_scale), devs)
+    to_dev, to_all = _placement(device, devs)
 
-    def to_dev(a):
-        if device is not None:
-            return jax.device_put(a, device)
-        return jax.numpy.asarray(a)
-
-    with enable_x64(), silence_unusable_donation():
+    with x64_streams(), silence_unusable_donation():
         lane_args = tuple(
             to_dev(pad(a)) for a in (
                 spec.workload_id.astype(np.int32, copy=False),
@@ -515,7 +526,7 @@ def replay_seeded_async(spec: SeededLaneSpec,
                 spec.variant_id.astype(np.int32, copy=False),
                 spec.limit.astype(np.float64, copy=False)))
         grid_args = tuple(
-            to_dev(a) for a in (
+            to_all(a) for a in (
                 spec.base_runtime.astype(np.float64, copy=False),
                 spec.low_num.astype(np.float64, copy=False),
                 spec.low_caps.astype(np.float64, copy=False),

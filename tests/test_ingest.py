@@ -426,6 +426,36 @@ def test_terminal_scorer_failure_degrades_not_dies(setup):
     assert not daemon.degraded  # failure != backpressure degradation
 
 
+def test_compile_failure_not_retried_and_not_swallowed(setup):
+    """A scoring program the compiler refuses fails every attempt the
+    same way: the service raises it at once (no retry, no backoff) and
+    the daemon counts the lost flush and lets the error out."""
+    from repro.fleet import ScorerCompileError
+
+    svc = _refusing_service(setup)
+    events = fleet_telemetry(MACHINES, rounds=1, runs_per_type=1,
+                             seed=63, interval=1.0, jitter=0.01)
+    daemon = IngestionDaemon(svc, capacity_rows=512,
+                             flush_interval=0.5, flush_rows=1 << 30,
+                             service_time_scale=0.0)
+    with pytest.raises(ScorerCompileError, match="block shape refused"):
+        daemon.run(events)
+    st = daemon.stats()
+    assert st["scorer_retries"] == 0
+    assert st["flush_failures"] == 1
+
+
+def _refusing_service(setup):
+    """A service whose scoring program the compiler refuses."""
+    svc = _service(setup)
+
+    def refused(*args):
+        raise ValueError("block shape refused by the TPU lowering")
+
+    svc.scorer._call = jax.jit(refused)
+    return svc
+
+
 # --------------------------------------------------------- threaded mode
 
 def test_threaded_serve_smoke(setup):
@@ -464,6 +494,81 @@ def test_threaded_serve_smoke(setup):
     assert sorted(res) == sorted(MACHINES)
     total = sum(len(r.anomaly_prob) for rs in res.values() for r in rs)
     assert total == sum(len(e.frame) for e in events)
+
+
+def test_threaded_compile_failure_stops_stream_and_raises(setup):
+    """Wall-clock mode: a compile error stops the serve thread, shows
+    as a failed flush in stats(), and close() raises it."""
+    from repro.fleet import ScorerCompileError
+
+    events = fleet_telemetry(MACHINES, rounds=1, runs_per_type=1,
+                             seed=64, interval=0.01)
+    pending = list(events)
+
+    def poll(now):
+        due, pending[:] = list(pending), []
+        return due
+
+    svc = _refusing_service(setup)
+    daemon = IngestionDaemon(svc, capacity_rows=512, flush_interval=0.05,
+                             flush_rows=1, service_time_scale=0.0)
+    daemon.attach_source(poll)
+    daemon.serve(poll_interval=0.01)
+    deadline = time.time() + 30.0
+    while not daemon._stop.is_set() and time.time() < deadline:
+        time.sleep(0.02)
+    assert daemon._stop.is_set(), "the serve thread kept running"
+    assert daemon.stats()["flush_failures"] == 1
+    assert daemon.stats()["scorer_retries"] == 0
+    with pytest.raises(ScorerCompileError, match="block shape refused"):
+        daemon.close(drain=True)
+    assert daemon._thread is None
+    assert daemon.stats()["flush_failures"] == 1  # close() drained nothing
+
+
+def test_warm_candidate_concurrent_with_flushes(setup):
+    """Warming a candidate from another thread (the model plane's
+    promote/rollback path) never leaks its params into live flushes:
+    the stored scores equal an incumbent-only run bit for bit, and the
+    incumbent's placement survives the candidate's."""
+    frame, pre, model, params = setup
+    events = fleet_telemetry(MACHINES, rounds=4, runs_per_type=1,
+                             seed=65, interval=1.0, jitter=0.01)
+
+    def run(candidate=None):
+        svc = _service(setup)
+        daemon = IngestionDaemon(svc, capacity_rows=512,
+                                 flush_interval=0.5, flush_rows=1 << 30,
+                                 service_time_scale=0.0)
+        stop = threading.Event()
+        warms = []
+
+        def warm_loop():
+            while not stop.wait(0.001):
+                warms.append(svc.warm(candidate))
+
+        t = threading.Thread(target=warm_loop) if candidate else None
+        if t is not None:
+            t.start()
+        try:
+            for ev in events:
+                daemon.run([ev], drain=False)
+            daemon.close(drain=True)
+        finally:
+            stop.set()
+            if t is not None:
+                t.join()
+        return svc, warms
+
+    ref, _ = run()
+    bad = jax.tree_util.tree_map(lambda x: np.asarray(x) * np.nan, params)
+    svc, warms = run(candidate=bad)
+    assert sum(warms) > 0, "the candidate was never dispatched"
+    np.testing.assert_array_equal(svc.store.anomaly, ref.store.anomaly)
+    assert np.isfinite(svc.store.anomaly[len(frame):]).all()
+    placed = svc.scorer.place_params(params)
+    assert svc.scorer.place_params(bad) is not placed
+    assert svc.scorer.place_params(params) is placed
 
 
 # ------------------------------------------- watchdog under faults (e2e)

@@ -40,13 +40,45 @@ def test_collective_parser_skips_done_ops():
 
 
 def test_roofline_terms_pick_dominant():
+    v5e = "TPU v5 lite"
     t = rl.roofline_terms(flops=197e12, bytes_accessed=819e9 / 2,
-                          coll_bytes=0)
+                          coll_bytes=0, device_kind=v5e)
     assert t["bottleneck"] == "compute"
     assert abs(t["compute_s"] - 1.0) < 1e-9
     t2 = rl.roofline_terms(flops=1e12, bytes_accessed=819e9 * 2,
-                           coll_bytes=0)
+                           coll_bytes=0, device_kind=v5e)
     assert t2["bottleneck"] == "memory"
+
+
+def test_roofline_unknown_device_kind_raises():
+    """Peaks come from the table or not at all: a chip without
+    published peaks must not be priced with another chip's."""
+    with pytest.raises(KeyError, match="no published peaks"):
+        rl.roofline_terms(flops=1.0, bytes_accessed=1.0, coll_bytes=0,
+                          device_kind="cpu")
+    from repro.launch.mesh import TARGET_DEVICE_KIND
+
+    assert rl.peaks(TARGET_DEVICE_KIND)["flops"] == 197e12
+
+
+def test_compile_cache_respects_the_environment(monkeypatch):
+    """An entry point's compile cache goes where
+    JAX_COMPILATION_CACHE_DIR says, with nothing set in code; without
+    it, to the checkout's fixed .jax_cache directory."""
+    from repro.common import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.CACHE_ENV, "/elsewhere/cache")
+        assert cc.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(cc.CACHE_ENV)
+        path = cc.enable_compile_cache()
+        assert path == str(cc.CHECKOUT_CACHE)
+        assert cc.CHECKOUT_CACHE.parent.joinpath("chip_smoke.py").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_model_flops_moe_counts_active_only():
@@ -89,7 +121,6 @@ def test_multi_device_lower_compile_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
         import jax
         from repro.configs import get_config
-        from repro.launch import roofline as rl
         from repro.launch.mesh import make_debug_mesh
         from repro.launch.steps import lowerable
         from repro.models.config import ShapeConfig
@@ -103,7 +134,7 @@ def test_multi_device_lower_compile_subprocess():
         with mesh:
             compiled = jax.jit(fn, in_shardings=shardings).lower(
                 *args).compile()
-        ca = rl.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         assert ca.get("flops", 0) > 0
         print("OK", int(ca["flops"]))
     """)

@@ -8,6 +8,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 from _trace_utils import expect_traces
@@ -53,13 +54,12 @@ def degraded_condition():
 def test_batched_gp_matches_scipy_reference():
     """Masked padded jnp fit/predict == dense scipy fit/predict."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.optimizer.gp import gp_fit, gp_predict
     from repro.tuning.gp import GP
 
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64():
         for m in (1, 2, 3, 5, 9):
             X = rng.normal(size=(m, 4))
             y = rng.normal(size=m) * 3.0 + 1.0
@@ -85,9 +85,27 @@ def test_batched_gp_matches_scipy_reference():
                                        ref.scales, rtol=0, atol=0)
 
 
+def test_gp_cholesky_matches_lapack():
+    """The hand-written factorization equals LAPACK's to rounding,
+    including a masked (identity-padded) observation block."""
+    import jax.numpy as jnp
+
+    from repro.optimizer.gp import cholesky
+
+    rng = np.random.default_rng(2)
+    a = rng.normal(size=(16, 16))
+    k = a @ a.T + 16.0 * np.eye(16)
+    k[10:, :] = 0.0
+    k[:, 10:] = 0.0
+    k[10:, 10:] = np.eye(6) * 1.001
+    with jax.enable_x64():
+        got = np.asarray(cholesky(jnp.asarray(k)))
+    np.testing.assert_allclose(got, np.linalg.cholesky(k), rtol=1e-12,
+                               atol=1e-12)
+
+
 def test_batched_ei_matches_numpy():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.optimizer.acquire import expected_improvement as ei_jnp
     from repro.tuning.gp import expected_improvement as ei_np
@@ -95,7 +113,7 @@ def test_batched_ei_matches_numpy():
     rng = np.random.default_rng(1)
     mu = rng.normal(size=50)
     sigma = np.abs(rng.normal(size=50)) + 1e-3
-    with enable_x64():
+    with jax.enable_x64():
         got = np.asarray(ei_jnp(jnp.asarray(mu), jnp.asarray(sigma),
                                 0.3))
     ref = ei_np(mu, sigma, 0.3)
@@ -135,7 +153,6 @@ def test_perona_lanes_reproduce_weighter_rankings(ds, machine_scores):
     """The pure-array weighting reproduces the sequential
     ``PeronaAcquisitionWeighter`` bit-for-bit on the same inputs."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.ranking import machine_score_matrix
     from repro.optimizer.acquire import perona_weight_factors
@@ -156,7 +173,7 @@ def test_perona_lanes_reproduce_weighter_rankings(ds, machine_scores):
     prices = np.asarray([PRICES[c.vm_type] for c in ds.configs])
     util = np.mean([ds.low_level_metrics(wl, c) for c in evaluated],
                    axis=0)
-    with enable_x64():
+    with jax.enable_x64():
         factors = np.asarray(perona_weight_factors(
             jnp.asarray(util), jnp.asarray(ns), jnp.asarray(prices),
             True))
@@ -245,8 +262,6 @@ def test_pipelined_matches_unpipelined(ds, machine_scores):
     assert stats["block_lanes"] == 8
     assert stats["blocks"] == stats["dispatches"] == 2
     assert stats["table_s"] > 0.0
-    import jax
-
     sharded = replay_pipelined(ds, scens, machine_scores,
                                block_lanes=8, devices=jax.devices(),
                                shard_blocks=True)

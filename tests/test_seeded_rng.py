@@ -11,7 +11,8 @@ import pytest
 
 from repro.common.rng import (STREAM_CONTENTION, bounded_uniform_grid,
                               folded_generator, lognormal_noise_grid,
-                              lognormal_noise_row, stream_key)
+                              lognormal_noise_row, stream_key,
+                              x64_streams)
 from repro.tuning.scout import (VM_TYPES, WORKLOAD_NAMES, ScoutDataset,
                                 all_configs, config_uid)
 
@@ -143,14 +144,13 @@ def test_noise_draws_identical_across_jit_and_vmap():
     program run jitted.)"""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     key = stream_key(0, STREAM_CONTENTION)
     uids = np.asarray([config_uid(c) for c in all_configs()], np.int32)
     grid = lognormal_noise_grid(key, len(WORKLOAD_NAMES), uids, 0.06)
     assert grid.shape == (len(WORKLOAD_NAMES), len(uids))
     assert grid.dtype == np.float64
-    with enable_x64():
+    with x64_streams():
         k, u = jnp.asarray(key), jnp.asarray(uids)
         row_eager = np.asarray(lognormal_noise_row(k, 3, u, 0.06))
         row_jit = np.asarray(jax.jit(
