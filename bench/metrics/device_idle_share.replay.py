@@ -1,0 +1,9 @@
+"""device_idle_share.replay (replay dispatch and device): 1 - the union of
+device-operation intervals over the traced window, from the profiler
+trace."""
+
+
+def read(run):
+    if run.trace_summary is None:
+        return None
+    return run.trace_summary["idle_share"]
