@@ -9,14 +9,21 @@ The observability substrate every subsystem reports through:
 - ``trace``    — span-based tracing with an *injectable clock*, so the
   ``IngestionDaemon`` virtual-clock ``run()`` and the wall-clock
   ``serve()`` both produce honest timelines, with explicit span
-  categories separating host work from device dispatch;
+  categories separating host work from device dispatch. Context-managed
+  spans also enter a ``jax.profiler.TraceAnnotation``, so a profiler
+  trace shows them on its own clock beside the device operations;
+  every event carries an ``id`` and its ``parent`` (the span open on
+  the same thread when it started);
 - ``timeline`` — export of recorded spans to Chrome trace-event JSON
   (loadable in Perfetto / ``chrome://tracing``) plus the schema
   validator shared by tests and the CI smoke step;
 - ``jaxstat``  — consolidated JIT accounting (:class:`JitSite`:
   tracings, dispatches, per-program compile/run wall seconds) behind
   the registry, replacing the per-module ad-hoc trace counters while
-  keeping their public ``count`` / ``trace_count`` reads;
+  keeping their public ``count`` / ``trace_count`` reads. At an
+  asynchronous site the dispatch span and its run seconds are the
+  launch; the wait for the outputs is the site's own span
+  (``replay.wait``);
 - ``regress``  — noise-aware perf-regression detection over benchmark
   history series (EWMA baselines sharing fleet drift's fold, noise
   floors calibrated from series scatter + A/A null rows, per-metric

@@ -15,12 +15,18 @@ established pattern), ``count`` and ``trace_count`` read the tracing
 counter, so ``tests/_trace_utils.expect_traces`` works on a
 :class:`JitSite` unchanged.
 
-:meth:`JitSite.dispatch` wraps one host-blocking compiled call: it
-books the wall time as *compile* when the site's trace counter
-advanced inside the call (first call per program signature) and as
-*run* otherwise, ticks the dispatch counter, and records a
-``CAT_DEVICE`` span on the current thread — which is how per-program
-compile/run splits and worker-thread device spans reach the timeline.
+:meth:`JitSite.dispatch` wraps one compiled call: it books the wall
+time as *compile* when the site's trace counter advanced inside the
+call (first call per program signature) and as *run* otherwise, ticks
+the dispatch counter, and records a ``CAT_DEVICE`` span through
+:meth:`repro.obs.trace.Tracer.span` on the current thread — on the
+profiler's clock, with the ``traced`` flag in its args — which is how
+per-program compile/run splits and worker-thread device spans reach
+the timeline. On an asynchronous backend a call returns once the
+program is launched, so at a site that fetches its outputs later
+(``optimizer.replay.replay_async``) the span and ``jax.run_s`` measure
+the *launch*; the device time shows in the site's own wait span
+(``replay.wait``).
 """
 
 from __future__ import annotations
@@ -86,26 +92,26 @@ class JitSite:
     def dispatch(self, name: Optional[str] = None,
                  args: Optional[Dict[str, object]] = None
                  ) -> Iterator[None]:
-        """Account one host-blocking compiled call (see module doc).
-        No-op when the plane is disabled."""
+        """Account one compiled call (see module doc). No-op when the
+        plane is disabled."""
         if not metrics.enabled():
             yield
             return
+        tr = self._tracer if self._tracer is not None \
+            else trace.tracer()
+        span_args = dict(args) if args else {}
         before = self._traces.value
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            traced = self._traces.value > before
-            (self._compile_s if traced else self._run_s).add(dt)
-            self._dispatches.inc()
-            tr = self._tracer if self._tracer is not None \
-                else trace.tracer()
-            span_args = dict(args) if args else {}
-            span_args["traced"] = traced
-            tr.complete(name if name is not None else self.site,
-                        trace.CAT_DEVICE, t0, dt, args=span_args)
+        with tr.span(name if name is not None else self.site,
+                     trace.CAT_DEVICE, args=span_args):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                traced = self._traces.value > before
+                (self._compile_s if traced else self._run_s).add(dt)
+                self._dispatches.inc()
+                span_args["traced"] = traced
 
     def stats(self) -> metrics.StatsDict:
         return {

@@ -5,7 +5,8 @@ trace-event JSON format (the ``{"traceEvents": [...]}`` flavor), which
 loads directly in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``: one process, one named track per originating
 thread, complete ``X`` events for spans and ``i`` instants for
-markers, span categories preserved in ``cat``.
+markers, span categories preserved in ``cat``, each event's ``id`` and
+``parent`` in its ``args``.
 
 Timestamps are microseconds relative to the earliest recorded event,
 so virtual-clock timelines (the daemon's ``run()``) and wall-clock
@@ -82,8 +83,8 @@ def chrome_trace(events: Optional[Sequence[SpanEvent]] = None, *,
             rec["s"] = "t"
         else:
             rec["ph"] = ev.ph
-        if ev.args:
-            rec["args"] = dict(ev.args)
+        rec["args"] = {**(ev.args or {}), "id": ev.id,
+                       "parent": ev.parent}
         out.append(rec)
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 

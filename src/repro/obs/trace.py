@@ -17,9 +17,24 @@ backpressure-ladder transitions. The timeline exporter
 (``repro.obs.timeline``) turns the recorded events into Chrome
 trace-event JSON, one track per originating thread.
 
+Two clocks. :meth:`Tracer.span` also enters a
+``jax.profiler.TraceAnnotation`` of the span's name, so while a
+profiler trace is running the span sits on the profiler's clock beside
+the device operations it caused (jax is imported on the first span,
+not with this module). :meth:`Tracer.complete` and
+:meth:`Tracer.instant` take explicit timestamps — the daemon's virtual
+flush windows — and stay off the profiler's clock. Program span names
+never start with ``bench.``: that prefix belongs to the benchmark's
+own annotations.
+
+Every event carries an ``id`` and the ``parent`` id of the span open
+on the same thread when it was recorded (``None`` at top level), from
+a per-tracer thread-local stack, so a span names its cause.
+
 Recording is a no-op while the plane is disabled
 (``obs.disable()``) — the ``span`` context manager yields immediately
-without reading the clock.
+without reading the clock, entering an annotation or touching the
+stack.
 """
 
 from __future__ import annotations
@@ -27,6 +42,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional
@@ -46,7 +62,9 @@ PH_INSTANT = "i"
 @dataclasses.dataclass(frozen=True)
 class SpanEvent:
     """One recorded event, timestamps in the tracer's clock domain
-    (seconds; ``dur`` is 0 for instants)."""
+    (seconds; ``dur`` is 0 for instants). ``id`` is unique within its
+    tracer; ``parent`` is the id of the span open on the same thread
+    when this event started, ``None`` at top level."""
 
     name: str
     cat: str
@@ -56,6 +74,22 @@ class SpanEvent:
     thread: str
     ph: str = PH_COMPLETE
     args: Optional[Dict[str, object]] = None
+    id: int = 0
+    parent: Optional[int] = None
+
+
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; jax is imported on the
+    first call only."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
 
 
 class Tracer:
@@ -68,6 +102,8 @@ class Tracer:
             maxlen=max_events)
         self._lock = threading.Lock()
         self._dropped = 0
+        self._ids = itertools.count(1)
+        self._local = threading.local()
 
     # ---------------------------------------------------------- clock
     def set_clock(self, clock: Callable[[], float]) -> None:
@@ -77,6 +113,18 @@ class Tracer:
         return self._clock()
 
     # ------------------------------------------------------ recording
+    def _stack(self) -> List[int]:
+        """Ids of the spans open on the calling thread, innermost
+        last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _parent(self) -> Optional[int]:
+        stack = self._stack()
+        return stack[-1] if stack else None
+
     def _record(self, ev: SpanEvent) -> None:
         with self._lock:
             if (self._events.maxlen is not None
@@ -88,21 +136,28 @@ class Tracer:
     def span(self, name: str, cat: str = CAT_HOST,
              args: Optional[Dict[str, object]] = None) -> Iterator[None]:
         """Record the block as one complete span on the current
-        thread. No-op (not even a clock read) when the plane is
-        disabled."""
+        thread, inside a profiler annotation of the same name. No-op
+        (not even a clock read) when the plane is disabled."""
         if not metrics.enabled():
             yield
             return
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            t1 = self._clock()
-            th = threading.current_thread()
-            self._record(SpanEvent(name=name, cat=cat, ts=t0,
-                                   dur=max(t1 - t0, 0.0),
-                                   tid=th.ident, thread=th.name,
-                                   args=args))
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        sid = next(self._ids)
+        with _annotation(name):
+            stack.append(sid)
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                t1 = self._clock()
+                stack.pop()
+                th = threading.current_thread()
+                self._record(SpanEvent(name=name, cat=cat, ts=t0,
+                                       dur=max(t1 - t0, 0.0),
+                                       tid=th.ident, thread=th.name,
+                                       args=args, id=sid,
+                                       parent=parent))
 
     def complete(self, name: str, cat: str, ts: float, dur: float,
                  args: Optional[Dict[str, object]] = None) -> None:
@@ -114,7 +169,9 @@ class Tracer:
         th = threading.current_thread()
         self._record(SpanEvent(name=name, cat=cat, ts=ts,
                                dur=max(dur, 0.0), tid=th.ident,
-                               thread=th.name, args=args))
+                               thread=th.name, args=args,
+                               id=next(self._ids),
+                               parent=self._parent()))
 
     def instant(self, name: str, cat: str = CAT_HOST,
                 args: Optional[Dict[str, object]] = None,
@@ -126,7 +183,9 @@ class Tracer:
         self._record(SpanEvent(name=name, cat=cat,
                                ts=self._clock() if ts is None else ts,
                                dur=0.0, tid=th.ident, thread=th.name,
-                               ph=PH_INSTANT, args=args))
+                               ph=PH_INSTANT, args=args,
+                               id=next(self._ids),
+                               parent=self._parent()))
 
     # -------------------------------------------------------- reading
     def events(self) -> List[SpanEvent]:

@@ -37,6 +37,7 @@ best-valid-cost curves (see tests/test_optimizer.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -46,12 +47,15 @@ import numpy as np
 
 from repro.common.mesh import (axis_specs, build_mesh, pad_lanes,
                                pow2_devices, shard_map_1d, shard_size)
+from repro.obs import trace as obs_trace
 from repro.obs.jaxstat import JitSite
 
 #: Ticked once per tracing of the scanned replay program — a
 #: registry-backed :class:`repro.obs.jaxstat.JitSite` whose
 #: ``dispatch()`` wrapper additionally books per-dispatch wall time
-#: into compile-vs-run registry counters and records a device span.
+#: into compile-vs-run registry counters and records the launch as a
+#: device span (the device time is the ``replay.wait`` span of
+#: :meth:`PendingReplay.result`).
 REPLAY_TRACES = JitSite("optimizer.replay")
 
 
@@ -350,6 +354,33 @@ def _placement(device, devs):
             lambda a: jax.device_put(a, grid))
 
 
+@contextlib.contextmanager
+def _staging(n_lanes: int, padded: int):
+    """The ``replay.stage`` span around padding, casting and placing a
+    dispatch's inputs; yields ``put(place, a)``, which places host
+    array ``a`` and adds its bytes to the span's ``bytes`` arg."""
+    args = {"bytes": 0, "lanes": n_lanes, "padded": padded}
+
+    def put(place, a):
+        args["bytes"] += a.nbytes
+        return place(a)
+
+    with obs_trace.span("replay.stage", args=args):
+        yield put
+
+
+def _initial_carry(put, to_dev, init_idx, cfg: ReplayConfig,
+                   lanes: int):
+    """Placed scan carry ``(sel, count, active)``: every lane starts
+    with its ``n_init`` seeded draws (``init_idx``, already padded to
+    ``lanes``) evaluated."""
+    sel0 = np.full((lanes, cfg.max_runs), -1, np.int32)
+    sel0[:, : cfg.n_init] = init_idx
+    count0 = np.full(lanes, cfg.n_init, np.int32)
+    active0 = np.ones(lanes, bool)
+    return tuple(put(to_dev, a) for a in (sel0, count0, active0))
+
+
 @dataclasses.dataclass
 class PendingReplay:
     """A dispatched-but-not-fetched replay: ``sel``/``count`` may still
@@ -361,8 +392,10 @@ class PendingReplay:
     _count: object
 
     def result(self) -> BatchReplayResult:
-        sel = np.asarray(self._sel)[: self.n_lanes]
-        count = np.asarray(self._count)[: self.n_lanes]
+        with obs_trace.span("replay.wait", cat=obs_trace.CAT_DEVICE,
+                            args={"lanes": self.n_lanes}):
+            sel = np.asarray(self._sel)[: self.n_lanes]
+            count = np.asarray(self._count)[: self.n_lanes]
         return BatchReplayResult(chosen=sel, count=count,
                                  dispatches=self.dispatches)
 
@@ -409,31 +442,28 @@ def replay_async(tables: LaneTables,
     def pad(a):  # pad the lane axis by repeating lane 0 (masked out)
         return pad_lanes(a, lanes)
 
-    sel0 = np.full((lanes, cfg.max_runs), -1, np.int32)
-    sel0[:, : cfg.n_init] = pad(tables.init_idx)
-    count0 = np.full(lanes, cfg.n_init, np.int32)
-    active0 = np.ones(lanes, bool)
-
     from repro.serving.engine import silence_unusable_donation
 
     fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, devs)
     to_dev, _ = _placement(device, devs)
 
     with jax.enable_x64(), silence_unusable_donation():
-        # copy=False: lane_tables already builds f64 columns, so the
-        # dtype casts are no-ops for the common path
-        jnp_tables = tuple(
-            to_dev(pad(a)) for a in (
-                tables.x_train.astype(np.float64, copy=False),
-                tables.x_cand.astype(np.float64, copy=False),
-                tables.y.astype(np.float64, copy=False),
-                tables.runtime.astype(np.float64, copy=False),
-                tables.util_low.astype(np.float64, copy=False),
-                tables.norm_scores.astype(np.float64, copy=False),
-                tables.price.astype(np.float64, copy=False),
-                tables.limit.astype(np.float64, copy=False),
-                tables.use_weighter.astype(bool, copy=False)))
-        carry0 = (to_dev(sel0), to_dev(count0), to_dev(active0))
+        with _staging(n_lanes, lanes) as put:
+            # copy=False: lane_tables already builds f64 columns, so
+            # the dtype casts are no-ops for the common path
+            jnp_tables = tuple(
+                put(to_dev, pad(a)) for a in (
+                    tables.x_train.astype(np.float64, copy=False),
+                    tables.x_cand.astype(np.float64, copy=False),
+                    tables.y.astype(np.float64, copy=False),
+                    tables.runtime.astype(np.float64, copy=False),
+                    tables.util_low.astype(np.float64, copy=False),
+                    tables.norm_scores.astype(np.float64, copy=False),
+                    tables.price.astype(np.float64, copy=False),
+                    tables.limit.astype(np.float64, copy=False),
+                    tables.use_weighter.astype(bool, copy=False)))
+            carry0 = _initial_carry(put, to_dev, pad(tables.init_idx),
+                                    cfg, lanes)
         # keyed on placement too: each device's first call compiles
         # its own executable and must take the serialized branch
         sig = (cfg, lanes, slots, n_cand, dim, rounds, devs, device)
@@ -506,11 +536,6 @@ def replay_seeded_async(spec: SeededLaneSpec,
     def pad(a):  # pad the lane axis by repeating lane 0 (masked out)
         return pad_lanes(a, lanes)
 
-    sel0 = np.full((lanes, cfg.max_runs), -1, np.int32)
-    sel0[:, : cfg.n_init] = pad(spec.init_idx)
-    count0 = np.full(lanes, cfg.n_init, np.int32)
-    active0 = np.ones(lanes, bool)
-
     from repro.serving.engine import silence_unusable_donation
 
     fn = _seeded_replay_fn(cfg, lanes, slots, n_cand, base_dim, rounds,
@@ -519,25 +544,27 @@ def replay_seeded_async(spec: SeededLaneSpec,
     to_dev, to_all = _placement(device, devs)
 
     with x64_streams(), silence_unusable_donation():
-        lane_args = tuple(
-            to_dev(pad(a)) for a in (
-                spec.workload_id.astype(np.int32, copy=False),
-                spec.condition_id.astype(np.int32, copy=False),
-                spec.variant_id.astype(np.int32, copy=False),
-                spec.limit.astype(np.float64, copy=False)))
-        grid_args = tuple(
-            to_all(a) for a in (
-                spec.base_runtime.astype(np.float64, copy=False),
-                spec.low_num.astype(np.float64, copy=False),
-                spec.low_caps.astype(np.float64, copy=False),
-                spec.x_base.astype(np.float64, copy=False),
-                spec.price.astype(np.float64, copy=False),
-                spec.count.astype(np.float64, copy=False),
-                spec.config_uid.astype(np.int32, copy=False),
-                ns.astype(np.float64, copy=False),
-                fp.astype(np.float64, copy=False),
-                spec.noise_key))
-        carry0 = (to_dev(sel0), to_dev(count0), to_dev(active0))
+        with _staging(n_lanes, lanes) as put:
+            lane_args = tuple(
+                put(to_dev, pad(a)) for a in (
+                    spec.workload_id.astype(np.int32, copy=False),
+                    spec.condition_id.astype(np.int32, copy=False),
+                    spec.variant_id.astype(np.int32, copy=False),
+                    spec.limit.astype(np.float64, copy=False)))
+            grid_args = tuple(
+                put(to_all, a) for a in (
+                    spec.base_runtime.astype(np.float64, copy=False),
+                    spec.low_num.astype(np.float64, copy=False),
+                    spec.low_caps.astype(np.float64, copy=False),
+                    spec.x_base.astype(np.float64, copy=False),
+                    spec.price.astype(np.float64, copy=False),
+                    spec.count.astype(np.float64, copy=False),
+                    spec.config_uid.astype(np.int32, copy=False),
+                    ns.astype(np.float64, copy=False),
+                    fp.astype(np.float64, copy=False),
+                    spec.noise_key))
+            carry0 = _initial_carry(put, to_dev, pad(spec.init_idx),
+                                    cfg, lanes)
         sig = ("seeded", cfg, lanes, slots, n_cand, base_dim, rounds,
                n_workloads, n_conds, devs, device)
         with REPLAY_TRACES.dispatch(
@@ -576,12 +603,14 @@ def traces_from_result(tables: LaneTables, result: BatchReplayResult,
     n = len(tables)
     if n == 0:
         return []
-    picks_all = result.chosen[:n]
-    idx = np.maximum(picks_all, 0)
-    costs_all = np.take_along_axis(tables.cost, idx, axis=1)
-    runtimes_all = np.take_along_axis(tables.runtime, idx, axis=1)
-    return _materialize_traces(picks_all, result.count[:n], costs_all,
-                               runtimes_all, tables.limit[:n], configs)
+    with obs_trace.span("replay.materialize_traces", args={"lanes": n}):
+        picks_all = result.chosen[:n]
+        idx = np.maximum(picks_all, 0)
+        costs_all = np.take_along_axis(tables.cost, idx, axis=1)
+        runtimes_all = np.take_along_axis(tables.runtime, idx, axis=1)
+        return _materialize_traces(picks_all, result.count[:n],
+                                   costs_all, runtimes_all,
+                                   tables.limit[:n], configs)
 
 
 def traces_from_spec(spec: SeededLaneSpec, result: BatchReplayResult,
@@ -592,13 +621,15 @@ def traces_from_spec(spec: SeededLaneSpec, result: BatchReplayResult,
     n = len(spec)
     if n == 0:
         return []
-    picks_all = result.chosen[:n]
-    idx = np.maximum(picks_all, 0)
-    wid = spec.workload_id[:n, None]
-    costs_all = spec.cost[wid, idx]
-    runtimes_all = spec.runtime[wid, idx]
-    return _materialize_traces(picks_all, result.count[:n], costs_all,
-                               runtimes_all, spec.limit[:n], configs)
+    with obs_trace.span("replay.materialize_traces", args={"lanes": n}):
+        picks_all = result.chosen[:n]
+        idx = np.maximum(picks_all, 0)
+        wid = spec.workload_id[:n, None]
+        costs_all = spec.cost[wid, idx]
+        runtimes_all = spec.runtime[wid, idx]
+        return _materialize_traces(picks_all, result.count[:n],
+                                   costs_all, runtimes_all,
+                                   spec.limit[:n], configs)
 
 
 def _materialize_traces(picks_all, counts, costs_all, runtimes_all,

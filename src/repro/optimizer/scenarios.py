@@ -228,17 +228,22 @@ def build_scenarios(ds: ScoutDataset, *,
     block runs on device. Building the matrix never resolves deferred
     conditions."""
     workloads = list(ds.workloads) if workloads is None else workloads
-    limits = {}
-    for wl in workloads:
-        rts, _, _ = ds.workload_arrays(wl)
-        limits[wl] = float(np.percentile(rts, limit_percentile))
-    if condition_major:
-        return [Scenario(wl, seed, variant, cond, limits[wl])
-                for cond in conditions for wl in workloads
-                for seed in seeds for variant in variants]
-    return [Scenario(wl, seed, variant, cond, limits[wl])
-            for wl in workloads for seed in seeds
-            for variant in variants for cond in conditions]
+    args: Dict[str, object] = {}
+    with obs_trace.span("replay.build_scenarios", args=args):
+        limits = {}
+        for wl in workloads:
+            rts, _, _ = ds.workload_arrays(wl)
+            limits[wl] = float(np.percentile(rts, limit_percentile))
+        if condition_major:
+            matrix = [Scenario(wl, seed, variant, cond, limits[wl])
+                      for cond in conditions for wl in workloads
+                      for seed in seeds for variant in variants]
+        else:
+            matrix = [Scenario(wl, seed, variant, cond, limits[wl])
+                      for wl in workloads for seed in seeds
+                      for variant in variants for cond in conditions]
+        args["lanes"] = len(matrix)
+    return matrix
 
 
 def _scenario_scores(scenario: Scenario, machine_scores):
@@ -288,87 +293,89 @@ def lane_tables(ds: ScoutDataset, scenarios: Sequence[Scenario],
     computes candidate features once, before any run is observed."""
     from repro.tuning.perona_weights import normalized_machine_scores
 
-    cfg = ReplayConfig() if cfg is None else cfg
-    configs = ds.configs
-    n_cand = len(configs)
-    x_base = np.stack([ds.config_features(c) for c in configs])
-    prices = np.asarray([PRICES[c.vm_type] for c in configs])
+    with obs_trace.span("replay.lane_tables",
+                        args={"lanes": len(scenarios)}):
+        cfg = ReplayConfig() if cfg is None else cfg
+        configs = ds.configs
+        n_cand = len(configs)
+        x_base = np.stack([ds.config_features(c) for c in configs])
+        prices = np.asarray([PRICES[c.vm_type] for c in configs])
 
-    workload_cache: Dict[str, Tuple] = {}
+        workload_cache: Dict[str, Tuple] = {}
 
-    def workload_tables(wl: str):
-        if wl not in workload_cache:
-            workload_cache[wl] = ds.workload_arrays(wl)
-        return workload_cache[wl]
+        def workload_tables(wl: str):
+            if wl not in workload_cache:
+                workload_cache[wl] = ds.workload_arrays(wl)
+            return workload_cache[wl]
 
-    # keyed by object identity: distinct conditions may share a name
-    cond_cache: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+        # keyed by object identity: distinct conditions may share a name
+        cond_cache: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
 
-    def condition_tables(cond: FleetCondition):
-        if id(cond) not in cond_cache:
-            scores = degrade_scores(machine_scores, cond)
-            norm = normalized_machine_scores(scores)
-            ns = np.stack([norm.get(c.vm_type, np.ones(4))
-                           for c in configs])
-            fp_low = machine_score_matrix(
-                scores, [c.vm_type for c in configs])
-            cond_cache[id(cond)] = (ns, fp_low)
-        return cond_cache[id(cond)]
+        def condition_tables(cond: FleetCondition):
+            if id(cond) not in cond_cache:
+                scores = degrade_scores(machine_scores, cond)
+                norm = normalized_machine_scores(scores)
+                ns = np.stack([norm.get(c.vm_type, np.ones(4))
+                               for c in configs])
+                fp_low = machine_score_matrix(
+                    scores, [c.vm_type for c in configs])
+                cond_cache[id(cond)] = (ns, fp_low)
+            return cond_cache[id(cond)]
 
-    dim = x_base.shape[1] + 4
-    n_lanes = len(scenarios)
-    tab = LaneTables(
-        x_train=np.zeros((n_lanes, n_cand, dim)),
-        x_cand=np.zeros((n_lanes, n_cand, dim)),
-        y=np.zeros((n_lanes, n_cand)),
-        runtime=np.zeros((n_lanes, n_cand)),
-        cost=np.zeros((n_lanes, n_cand)),
-        limit=np.zeros(n_lanes),
-        price=np.tile(prices, (n_lanes, 1)),
-        norm_scores=np.zeros((n_lanes, n_cand, 4)),
-        util_low=np.zeros((n_lanes, n_cand, 4)),
-        use_weighter=np.zeros(n_lanes, bool),
-        init_idx=np.zeros((n_lanes, cfg.n_init), np.int32))
+        dim = x_base.shape[1] + 4
+        n_lanes = len(scenarios)
+        tab = LaneTables(
+            x_train=np.zeros((n_lanes, n_cand, dim)),
+            x_cand=np.zeros((n_lanes, n_cand, dim)),
+            y=np.zeros((n_lanes, n_cand)),
+            runtime=np.zeros((n_lanes, n_cand)),
+            cost=np.zeros((n_lanes, n_cand)),
+            limit=np.zeros(n_lanes),
+            price=np.tile(prices, (n_lanes, 1)),
+            norm_scores=np.zeros((n_lanes, n_cand, 4)),
+            util_low=np.zeros((n_lanes, n_cand, 4)),
+            use_weighter=np.zeros(n_lanes, bool),
+            init_idx=np.zeros((n_lanes, cfg.n_init), np.int32))
 
-    base_dim = x_base.shape[1]
-    tab.x_train[:, :, :base_dim] = x_base
-    tab.x_cand[:, :, :base_dim] = x_base
-    # lanes sharing (workload, condition, variant, limit) get identical
-    # rows: assign per group (one fancy-index write each) instead of
-    # per lane — the python work is O(groups + lanes), which keeps
-    # table construction cheap enough to overlap with device scans
-    groups: Dict[Tuple, List[int]] = {}
-    for lane, sc in enumerate(scenarios):
-        groups.setdefault(
-            (sc.workload, id(sc.condition), sc.variant, sc.limit),
-            []).append(lane)
-    for (wl, _, variant, limit), lanes in groups.items():
-        sc = scenarios[lanes[0]]
-        rows = np.asarray(lanes)
-        runtimes, costs, lows = workload_tables(wl)
-        ns, fp_low = condition_tables(sc.condition)
-        if variant == "arrow":
-            # evaluated runs carry their observed low-level metrics;
-            # candidates keep the search-start zeros block
-            tab.x_train[rows, :, base_dim:] = lows
-        elif variant == "arrow+perona":
-            # fingerprint scores exist before any run: both sides
-            tab.x_train[rows, :, base_dim:] = fp_low
-            tab.x_cand[rows, :, base_dim:] = fp_low
-        tab.runtime[rows] = runtimes
-        tab.cost[rows] = costs
-        tab.y[rows] = np.where(runtimes <= limit, costs, costs * 5.0)
-        tab.limit[rows] = limit
-        tab.norm_scores[rows] = ns
-        tab.util_low[rows] = lows
-        tab.use_weighter[rows] = variant.endswith("+perona")
-    init_cache: Dict[int, np.ndarray] = {}
-    for lane, sc in enumerate(scenarios):
-        if sc.seed not in init_cache:
-            init_cache[sc.seed] = np.random.default_rng(sc.seed).choice(
-                n_cand, cfg.n_init, replace=False).astype(np.int32)
-        tab.init_idx[lane] = init_cache[sc.seed]
-    return tab
+        base_dim = x_base.shape[1]
+        tab.x_train[:, :, :base_dim] = x_base
+        tab.x_cand[:, :, :base_dim] = x_base
+        # lanes sharing (workload, condition, variant, limit) get identical
+        # rows: assign per group (one fancy-index write each) instead of
+        # per lane — the python work is O(groups + lanes), which keeps
+        # table construction cheap enough to overlap with device scans
+        groups: Dict[Tuple, List[int]] = {}
+        for lane, sc in enumerate(scenarios):
+            groups.setdefault(
+                (sc.workload, id(sc.condition), sc.variant, sc.limit),
+                []).append(lane)
+        for (wl, _, variant, limit), lanes in groups.items():
+            sc = scenarios[lanes[0]]
+            rows = np.asarray(lanes)
+            runtimes, costs, lows = workload_tables(wl)
+            ns, fp_low = condition_tables(sc.condition)
+            if variant == "arrow":
+                # evaluated runs carry their observed low-level metrics;
+                # candidates keep the search-start zeros block
+                tab.x_train[rows, :, base_dim:] = lows
+            elif variant == "arrow+perona":
+                # fingerprint scores exist before any run: both sides
+                tab.x_train[rows, :, base_dim:] = fp_low
+                tab.x_cand[rows, :, base_dim:] = fp_low
+            tab.runtime[rows] = runtimes
+            tab.cost[rows] = costs
+            tab.y[rows] = np.where(runtimes <= limit, costs, costs * 5.0)
+            tab.limit[rows] = limit
+            tab.norm_scores[rows] = ns
+            tab.util_low[rows] = lows
+            tab.use_weighter[rows] = variant.endswith("+perona")
+        init_cache: Dict[int, np.ndarray] = {}
+        for lane, sc in enumerate(scenarios):
+            if sc.seed not in init_cache:
+                init_cache[sc.seed] = np.random.default_rng(sc.seed).choice(
+                    n_cand, cfg.n_init, replace=False).astype(np.int32)
+            tab.init_idx[lane] = init_cache[sc.seed]
+        return tab
 
 
 def lane_spec(ds: ScoutDataset, scenarios: Sequence[Scenario],
@@ -382,57 +389,58 @@ def lane_spec(ds: ScoutDataset, scenarios: Sequence[Scenario],
     the contention noise re-drawn on device from ``ds.grid.noise_key``
     counter-based keys."""
     from repro.tuning.perona_weights import normalized_machine_scores
-
-    cfg = ReplayConfig() if cfg is None else cfg
-    configs = ds.configs
-    n_cand = len(configs)
-    grid = ds.grid
-    n_lanes = len(scenarios)
-
-    # one score-matrix pair per distinct condition object (identity
-    # keyed: distinct conditions may share a name); resolving a
-    # deferred condition happens here, on the host, thread-safely
-    cond_rows: Dict[int, int] = {}
-    ns_rows: List[np.ndarray] = []
-    fp_rows: List[np.ndarray] = []
-    condition_id = np.empty(n_lanes, np.int32)
-    workload_id = np.empty(n_lanes, np.int32)
-    variant_id = np.empty(n_lanes, np.int32)
-    limit = np.empty(n_lanes, np.float64)
-    init_idx = np.zeros((n_lanes, cfg.n_init), np.int32)
-    init_cache: Dict[int, np.ndarray] = {}
-    for lane, sc in enumerate(scenarios):
-        row = cond_rows.get(id(sc.condition))
-        if row is None:
-            scores = degrade_scores(machine_scores, sc.condition)
-            norm = normalized_machine_scores(scores)
-            ns_rows.append(np.stack([norm.get(c.vm_type, np.ones(4))
-                                     for c in configs]))
-            fp_rows.append(machine_score_matrix(
-                scores, [c.vm_type for c in configs]))
-            row = cond_rows[id(sc.condition)] = len(ns_rows) - 1
-        condition_id[lane] = row
-        workload_id[lane] = ds.workload_id(sc.workload)
-        variant_id[lane] = VARIANTS.index(sc.variant)
-        limit[lane] = sc.limit
-        if sc.seed not in init_cache:
-            init_cache[sc.seed] = np.random.default_rng(sc.seed).choice(
-                n_cand, cfg.n_init, replace=False).astype(np.int32)
-        init_idx[lane] = init_cache[sc.seed]
-
     from repro.tuning.scout import CONTENTION_SCALE
 
-    return SeededLaneSpec(
-        base_runtime=grid.base_runtime, low_num=grid.low_num,
-        low_caps=np.asarray(LOW_CAPS, np.float64),
-        x_base=grid.x_base, price=grid.price,
-        count=grid.count.astype(np.float64, copy=False),
-        config_uid=grid.config_uid,
-        norm_scores=np.stack(ns_rows), fp_low=np.stack(fp_rows),
-        noise_key=grid.noise_key, noise_scale=CONTENTION_SCALE,
-        workload_id=workload_id, condition_id=condition_id,
-        variant_id=variant_id, limit=limit, init_idx=init_idx,
-        runtime=grid.runtime, cost=grid.cost)
+    with obs_trace.span("replay.lane_spec",
+                        args={"lanes": len(scenarios)}):
+        cfg = ReplayConfig() if cfg is None else cfg
+        configs = ds.configs
+        n_cand = len(configs)
+        grid = ds.grid
+        n_lanes = len(scenarios)
+
+        # one score-matrix pair per distinct condition object (identity
+        # keyed: distinct conditions may share a name); resolving a
+        # deferred condition happens here, on the host, thread-safely
+        cond_rows: Dict[int, int] = {}
+        ns_rows: List[np.ndarray] = []
+        fp_rows: List[np.ndarray] = []
+        condition_id = np.empty(n_lanes, np.int32)
+        workload_id = np.empty(n_lanes, np.int32)
+        variant_id = np.empty(n_lanes, np.int32)
+        limit = np.empty(n_lanes, np.float64)
+        init_idx = np.zeros((n_lanes, cfg.n_init), np.int32)
+        init_cache: Dict[int, np.ndarray] = {}
+        for lane, sc in enumerate(scenarios):
+            row = cond_rows.get(id(sc.condition))
+            if row is None:
+                scores = degrade_scores(machine_scores, sc.condition)
+                norm = normalized_machine_scores(scores)
+                ns_rows.append(np.stack([norm.get(c.vm_type, np.ones(4))
+                                         for c in configs]))
+                fp_rows.append(machine_score_matrix(
+                    scores, [c.vm_type for c in configs]))
+                row = cond_rows[id(sc.condition)] = len(ns_rows) - 1
+            condition_id[lane] = row
+            workload_id[lane] = ds.workload_id(sc.workload)
+            variant_id[lane] = VARIANTS.index(sc.variant)
+            limit[lane] = sc.limit
+            if sc.seed not in init_cache:
+                init_cache[sc.seed] = np.random.default_rng(sc.seed).choice(
+                    n_cand, cfg.n_init, replace=False).astype(np.int32)
+            init_idx[lane] = init_cache[sc.seed]
+
+        return SeededLaneSpec(
+            base_runtime=grid.base_runtime, low_num=grid.low_num,
+            low_caps=np.asarray(LOW_CAPS, np.float64),
+            x_base=grid.x_base, price=grid.price,
+            count=grid.count.astype(np.float64, copy=False),
+            config_uid=grid.config_uid,
+            norm_scores=np.stack(ns_rows), fp_low=np.stack(fp_rows),
+            noise_key=grid.noise_key, noise_scale=CONTENTION_SCALE,
+            workload_id=workload_id, condition_id=condition_id,
+            variant_id=variant_id, limit=limit, init_idx=init_idx,
+            runtime=grid.runtime, cost=grid.cost)
 
 
 def replay_scenarios(ds: ScoutDataset, scenarios: Sequence[Scenario],
@@ -536,7 +544,7 @@ def replay_pipelined(ds: ScoutDataset, scenarios: Sequence[Scenario],
         # worker thread: dispatch + device wait (GIL released inside
         # XLA); per-device workers keep each device's blocks in order.
         # The span lands on the worker's own timeline track — its
-        # overlap with the main thread's replay.build_tables spans IS
+        # overlap with the main thread's replay.lane_tables spans IS
         # the pipelining (asserted in tests/test_obs.py).
         with obs_trace.span("replay.block_scan",
                             cat=obs_trace.CAT_DEVICE,
@@ -551,14 +559,10 @@ def replay_pipelined(ds: ScoutDataset, scenarios: Sequence[Scenario],
     def collect(tab, future):
         result = future.result()
         stats["dispatches"] += result.dispatches
-        with obs_trace.span("replay.materialize_traces",
-                            args={"lanes": len(tab)}):
-            if seeded:
-                traces.extend(
-                    traces_from_spec(tab, result, ds.configs))
-            else:
-                traces.extend(
-                    traces_from_result(tab, result, ds.configs))
+        if seeded:
+            traces.extend(traces_from_spec(tab, result, ds.configs))
+        else:
+            traces.extend(traces_from_result(tab, result, ds.configs))
 
     in_flight: List = []  # (tables, future), submission order
     # one single-worker pool per device: a device's blocks dispatch in
@@ -568,14 +572,12 @@ def replay_pipelined(ds: ScoutDataset, scenarios: Sequence[Scenario],
     try:
         for i, start in enumerate(range(0, len(scenarios), block)):
             chunk = scenarios[start:start + block]
-            t0 = time.perf_counter()  # host work, overlapped with the
-            with obs_trace.span("replay.build_tables",
-                                args={"block": i,
-                                      "lanes": len(chunk)}):
-                if seeded:
-                    tab = lane_spec(ds, chunk, machine_scores, cfg)
-                else:
-                    tab = lane_tables(ds, chunk, machine_scores, cfg)
+            # host work, overlapped with earlier blocks' device scans
+            t0 = time.perf_counter()
+            if seeded:
+                tab = lane_spec(ds, chunk, machine_scores, cfg)
+            else:
+                tab = lane_tables(ds, chunk, machine_scores, cfg)
             stats["table_s"] += time.perf_counter() - t0
             d = i % len(devs)
             in_flight.append(

@@ -5,6 +5,7 @@ two end-to-end timelines the PR promises — a daemon fault storm with
 named ladder transitions, and a pipelined replay whose host table
 builds overlap device block scans on separate thread tracks."""
 
+import contextlib
 import json
 import threading
 
@@ -14,6 +15,7 @@ import pytest
 
 from repro import obs
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.obs.jaxstat import JitSite, instance_site
 from repro.obs.timeline import (chrome_trace, validate_chrome_trace,
                                 validate_chrome_trace_file,
@@ -110,7 +112,8 @@ def test_histogram_empty_and_nonpositive():
 
 # ---------------------------------------------------- disabled mode
 
-def test_disabled_mode_noops_everything():
+def test_disabled_mode_noops_everything(monkeypatch):
+    entered = _count_annotations(monkeypatch)
     reg = obs_metrics.MetricsRegistry()
     c = reg.counter("off.hits")
     h = reg.histogram("off.lat")
@@ -121,14 +124,15 @@ def test_disabled_mode_noops_everything():
         c.inc(5)
         h.observe(1.0)
         with tr.span("s"):
-            pass
+            assert tr._stack() == []
+            with site.dispatch("d"):
+                assert tr._stack() == []
         tr.instant("i")
         tr.complete("c", CAT_HOST, 0.0, 1.0)
-        with site.dispatch("d"):
-            pass
     assert obs.enabled()  # restored
     assert c.value == 0 and h.count == 0
     assert tr.events() == []
+    assert entered == [] and tr._stack() == []
     assert site.dispatches == 0
     assert site.compile_seconds == 0.0 and site.run_seconds == 0.0
     # re-enabled: everything records again
@@ -136,9 +140,24 @@ def test_disabled_mode_noops_everything():
     with tr.span("s2"):
         pass
     assert c.value == 1 and len(tr.events()) == 1
+    assert entered == ["s2"]
 
 
 # ------------------------------------------------------------ tracer
+
+def _count_annotations(monkeypatch):
+    """Replace the profiler annotation the tracer enters with one that
+    notes each name entered; returns the list of names."""
+    entered = []
+
+    @contextlib.contextmanager
+    def note(name):
+        entered.append(name)
+        yield
+
+    monkeypatch.setattr(obs_trace, "_annotation", note)
+    return entered
+
 
 def test_tracer_spans_and_injectable_clock():
     clock = {"t": 10.0}
@@ -155,6 +174,60 @@ def test_tracer_spans_and_injectable_clock():
     assert [e.name for e in tr.events()] == ["work", "mark", "flush"]
     tr.clear()
     assert tr.events() == [] and tr.dropped == 0
+
+
+def test_nested_spans_name_their_parent(monkeypatch):
+    entered = _count_annotations(monkeypatch)
+    tr = Tracer()
+    seen = {}
+
+    def worker():
+        with tr.span("other_thread"):
+            pass
+
+    with tr.span("outer"):
+        with tr.span("inner"):
+            tr.instant("mark")
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+        tr.complete("flush", CAT_HOST, ts=0.0, dur=1.0)
+        with tr.span("sibling"):
+            seen["stack"] = list(tr._stack())
+    with tr.span("after"):
+        pass
+    evs = {e.name: e for e in tr.events()}
+    assert len({e.id for e in evs.values()}) == len(evs)
+    assert all(e.id > 0 for e in evs.values())
+    assert evs["outer"].parent is None and evs["after"].parent is None
+    assert evs["inner"].parent == evs["outer"].id
+    assert evs["mark"].parent == evs["inner"].id
+    assert evs["flush"].parent == evs["outer"].id
+    assert evs["sibling"].parent == evs["outer"].id
+    assert seen["stack"] == [evs["outer"].id, evs["sibling"].id]
+    # a span on another thread starts its own stack
+    assert evs["other_thread"].parent is None
+    assert tr._stack() == []
+    # every context-managed span enters one annotation; complete and
+    # instant stay off the profiler's clock
+    assert sorted(entered) == sorted(
+        ["outer", "inner", "other_thread", "sibling", "after"])
+    # the exporter carries both ids in args
+    args = {e["name"]: e["args"] for e in chrome_trace(tr.events())
+            ["traceEvents"] if e["ph"] != "M"}
+    assert args["inner"]["id"] == evs["inner"].id
+    assert args["inner"]["parent"] == evs["outer"].id
+    assert args["outer"]["parent"] is None
+
+
+def test_span_exception_unwinds_the_stack():
+    tr = Tracer()
+    with pytest.raises(RuntimeError):
+        with tr.span("fails"):
+            raise RuntimeError("boom")
+    (ev,) = tr.events()
+    assert ev.name == "fails" and tr._stack() == []
 
 
 def test_tracer_ring_bounds_and_drop_count():
@@ -262,11 +335,17 @@ def test_jitsite_attributes_compile_vs_run():
     assert site.compile_seconds > 0.0 and site.run_seconds > 0.0
     evs = tr.events()
     assert [e.cat for e in evs] == [CAT_DEVICE, CAT_DEVICE]
-    assert evs[0].args["traced"] is True
-    assert evs[1].args["traced"] is False
+    assert evs[0].args == {"n": 1, "traced": True}
+    assert evs[1].args == {"n": 2, "traced": False}
     st = site.stats()
     assert st["traces"] == 1 and st["dispatches"] == 2
     assert reg.snapshot()["jax.traces{site=t.site}"] == 1
+    # a dispatch is a span: it nests under the span open around it
+    with tr.span("caller"):
+        with site.dispatch("call"):
+            pass
+    caller, call = tr.events()[-1], tr.events()[-2]
+    assert call.parent == caller.id and call.args["traced"] is False
 
 
 def test_instance_site_labels_are_unique():
@@ -459,7 +538,7 @@ def test_pipelined_replay_host_device_overlap(tmp_path):
     assert len(traces) == len(scens)
 
     evs = tr.events()
-    builds = [e for e in evs if e.name == "replay.build_tables"]
+    builds = [e for e in evs if e.name == "replay.lane_tables"]
     scans = [e for e in evs if e.name == "replay.block_scan"]
     assert len(builds) == len(scans) == len(scens) // 4
     assert all(e.cat == CAT_DEVICE for e in scans)
@@ -474,6 +553,53 @@ def test_pipelined_replay_host_device_overlap(tmp_path):
     write_chrome_trace(path, tracer=tr)
     summary = validate_chrome_trace_file(path)
     assert summary["threads"] >= 2
+
+
+REPLAY_SPANS = ("replay.build_scenarios", "replay.lane_tables",
+                "replay.stage", "replay.dispatch", "replay.wait",
+                "replay.materialize_traces")
+
+
+def test_replay_spans_on_the_profiler_clock(tmp_path):
+    """Under a CPU profiler trace, every replay span of one search is a
+    host annotation nested inside the enclosing span's annotation, and
+    the obs events name that span as their parent."""
+    from jax.profiler import ProfileData
+
+    from repro.optimizer import (HEALTHY, build_scenarios,
+                                 replay_scenarios)
+    from repro.tuning.scout import ScoutDataset, VM_TYPES, \
+        WORKLOAD_NAMES
+
+    ds = ScoutDataset(seed=0)
+    scores = {vm: {a: 1.0 for a in ("cpu", "memory", "disk", "network")}
+              for vm in VM_TYPES}
+    tr = obs.tracer()
+    tr.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("test.search"):
+            scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
+                                    seeds=(0, 1), conditions=(HEALTHY,))
+            replay_scenarios(ds, scens, scores)
+    finally:
+        jax.profiler.stop_trace()
+
+    evs = tr.events()
+    outer = next(e for e in evs if e.name == "test.search")
+    replay = [e for e in evs if e.name.startswith("replay.")]
+    assert sorted(e.name for e in replay) == sorted(REPLAY_SPANS)
+    assert all(e.parent == outer.id for e in replay)
+
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    (_, lo, hi) = next(h for h in host if h[0] == "test.search")
+    inside = {name for name, a, b in host
+              if name.startswith("replay.") and lo <= a and b <= hi}
+    assert inside == set(REPLAY_SPANS)
 
 
 def test_service_stats_traces_through_registry(setup):
