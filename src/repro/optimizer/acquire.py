@@ -5,7 +5,10 @@ Perona acquisition weighting mirrors ``tuning.perona_weights.
 PeronaAcquisitionWeighter.__call__`` — both are the numpy references
 the parity tests pin against. Inputs arrive precomputed as matrices
 (normalized machine-score rows per candidate configuration, observed
-utilization per evaluated run), so a weighting step is two matvecs.
+utilization per evaluated run), so a weighting step is one
+contraction over the 4 aspects per candidate. It is written as a
+multiply and sum, not ``@``: on the TPU a float64 dot compiles to a
+multi-pass bfloat16 loop (see ``optimizer.gp``).
 """
 
 from __future__ import annotations
@@ -36,7 +39,7 @@ def perona_weight_factors(util: jnp.ndarray, norm_scores: jnp.ndarray,
     Two-phase prior: capability while no valid configuration is known
     (``any_valid`` False), capability per dollar once one exists."""
     util = util / jnp.maximum(jnp.sum(util), 1e-9)
-    w = norm_scores @ util
+    w = jnp.sum(norm_scores * util, axis=-1)
     w = jnp.where(jnp.logical_and(per_dollar, any_valid), w / prices, w)
     w = w / jnp.maximum(jnp.mean(w), 1e-9)
     return 1.0 + strength * (w - 1.0)

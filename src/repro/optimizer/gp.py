@@ -12,6 +12,16 @@ Masking convention: padded observation rows contribute an identity
 block to the kernel matrix (diagonal 1 + noise, zero cross terms) and a
 zero target, so their Cholesky/solve contributions vanish exactly —
 fit/predict on a masked set equals fit/predict on the dense subset.
+
+Every contraction is written as a broadcast multiply and a ``jnp.sum``
+over the contracted axis, never as ``@``/``dot`` or a triangular solve:
+the TPU has no float64 matmul unit, and its compiler emulates each
+float64 dot or solve as a ``while`` loop of bfloat16 passes over split
+operands. The contractions here are 4 to 16 long, so those passes
+would run on tiles that are nearly all padding; as multiply-and-sum
+they fuse into the surrounding elementwise code, and the scanned BO
+round of ``optimizer.replay`` compiles to a loop-free body. On the CPU a
+contraction this short gains nothing from a BLAS call either.
 """
 
 from __future__ import annotations
@@ -21,13 +31,13 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 import jax.numpy as jnp
-from jax.scipy.linalg import cho_solve, solve_triangular
 
 
 class GPState(NamedTuple):
     """Posterior state of one fitted lane (a pytree; vmap-friendly)."""
 
-    chol: jnp.ndarray  # (P, P) lower Cholesky of K + noise*I
+    l_inv: jnp.ndarray  # (P, P) inverse of the lower Cholesky factor
+    #                       of K + noise*I (lower triangular)
     alpha: jnp.ndarray  # (P,) K^-1 y_standardized
     x: jnp.ndarray  # (P, D) padded observations
     mask: jnp.ndarray  # (P,) observation validity
@@ -69,14 +79,16 @@ def median_scales(x: jnp.ndarray, mask: jnp.ndarray, m: jnp.ndarray,
 
 def _kernel(a: jnp.ndarray, b: jnp.ndarray,
             scales: jnp.ndarray) -> jnp.ndarray:
-    """RBF kernel via the matmul expansion |a'|^2 + |b'|^2 - 2 a'.b'
-    of the scaled squared distance (BLAS-friendly; clipped at 0 so
-    self-distances stay exactly zero under rounding)."""
+    """RBF kernel via the expansion |a'|^2 + |b'|^2 - 2 a'.b' of the
+    scaled squared distance (clipped at 0 so self-distances stay
+    exactly zero under rounding); a'.b' as multiply-and-sum (module
+    docstring)."""
     a = a / scales
     b = b / scales
     na = jnp.sum(a * a, axis=-1)
     nb = jnp.sum(b * b, axis=-1)
-    sq = jnp.maximum(na[:, None] + nb[None, :] - 2.0 * (a @ b.T), 0.0)
+    ab = jnp.sum(a[:, None, :] * b[None, :, :], axis=-1)
+    sq = jnp.maximum(na[:, None] + nb[None, :] - 2.0 * ab, 0.0)
     return jnp.exp(-0.5 * sq)
 
 
@@ -88,14 +100,34 @@ def cholesky(k: jnp.ndarray) -> jnp.ndarray:
     automatic sharding: "A tuple parameter that is being flattened
     shouldn't have frontend attributes") — the sharded replay is one.
     ``k`` is P x P with P the pow2 observation-slot count, so the
-    unrolled loop stays short."""
+    unrolled loop stays short. The column update is a multiply and sum,
+    not a matvec: a float64 matvec would compile to a multi-pass loop
+    on the TPU (module docstring)."""
     p = k.shape[-1]
     chol = jnp.zeros_like(k)
     for j in range(p):
         d = jnp.sqrt(k[j, j] - jnp.sum(chol[j, :j] ** 2))
-        col = (k[j + 1:, j] - chol[j + 1:, :j] @ chol[j, :j]) / d
+        col = (k[j + 1:, j]
+               - jnp.sum(chol[j + 1:, :j] * chol[j, :j], axis=-1)) / d
         chol = chol.at[j, j].set(d).at[j + 1:, j].set(col)
     return chol
+
+
+def tril_inverse(chol: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of a lower-triangular factor, row by row by forward
+    substitution: ``row_j = (e_j - sum_{i<j} L[j,i] inv[i,:]) / L[j,j]``.
+
+    Unrolled over the P slots like :func:`cholesky`, in place of a
+    triangular solve (module docstring). A masked slot's identity
+    block in ``chol`` stays an identity block (scaled by its diagonal)
+    in the inverse."""
+    p = chol.shape[-1]
+    eye = jnp.eye(p, dtype=chol.dtype)
+    inv = jnp.zeros_like(chol)
+    for j in range(p):
+        acc = jnp.sum(chol[j, :j, None] * inv[:j], axis=0)
+        inv = inv.at[j].set((eye[j] - acc) / chol[j, j])
+    return inv
 
 
 def gp_fit(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray,
@@ -119,9 +151,10 @@ def gp_fit(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray,
     pmask = mask[:, None] & mask[None, :]
     k = jnp.where(pmask, _kernel(x, x, scales), 0.0)
     k = k + jnp.diag(jnp.where(mask, noise, 1.0 + noise))
-    chol = cholesky(k)
-    alpha = cho_solve((chol, True), yn[:, None])[:, 0]
-    return GPState(chol=chol, alpha=alpha, x=x, mask=mask,
+    l_inv = tril_inverse(cholesky(k))
+    # alpha = L^-T (L^-1 yn)
+    alpha = jnp.sum(l_inv * jnp.sum(l_inv * yn, axis=-1)[:, None], axis=0)
+    return GPState(l_inv=l_inv, alpha=alpha, x=x, mask=mask,
                    scales=scales, y_mean=y_mean, y_std=y_std)
 
 
@@ -129,16 +162,14 @@ def gp_predict(state: GPState, xs: jnp.ndarray):
     """Posterior (mu, sigma) at candidate points ``xs`` (C, D).
 
     The predictive variance 1 - k* K^-1 k*^T is computed as
-    1 - ||L^-1 k*^T||^2, with L^-1 materialized once per fit state (a
-    P x P triangular solve) so the per-candidate work is one matmul
-    (equal to the reference's cho_solve form up to rounding; the
-    selection grid in the replay engine absorbs the ulp difference)."""
+    1 - ||L^-1 k*^T||^2 with the L^-1 that :func:`gp_fit` carries in
+    the state, so the per-candidate work is two multiply-and-sums, no
+    solve (module docstring). Equal to the reference's cho_solve form
+    up to rounding; the selection grid in the replay engine absorbs
+    the ulp difference."""
     ks = _kernel(xs, state.x, state.scales) * state.mask[None, :]
-    mu = ks @ state.alpha
-    p = state.chol.shape[0]
-    l_inv = solve_triangular(state.chol, jnp.eye(p, dtype=ks.dtype),
-                             lower=True)
-    w = l_inv @ ks.T
+    mu = jnp.sum(ks * state.alpha, axis=-1)
+    w = jnp.sum(state.l_inv[:, None, :] * ks[None, :, :], axis=-1)
     var = jnp.clip(1.0 - jnp.sum(w * w, axis=0), 1e-9, None)
     return (mu * state.y_std + state.y_mean,
             jnp.sqrt(var) * state.y_std)

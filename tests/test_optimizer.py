@@ -104,6 +104,32 @@ def test_gp_cholesky_matches_lapack():
                                atol=1e-12)
 
 
+@pytest.mark.parametrize("masked_from", [16, 10, 3, 1])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_tril_inverse_matches_numpy(seed, masked_from):
+    """The unrolled inverse of the Cholesky factor equals numpy's
+    inverse of LAPACK's factor, including matrices whose trailing slots
+    are the masked identity block (1 + noise on the diagonal)."""
+    import jax.numpy as jnp
+
+    from repro.optimizer.gp import cholesky, tril_inverse
+
+    rng = np.random.default_rng(seed)
+    a = rng.normal(size=(16, 16))
+    k = a @ a.T + 16.0 * np.eye(16)
+    k[masked_from:, :] = 0.0
+    k[:, masked_from:] = 0.0
+    k[masked_from:, masked_from:] = np.eye(16 - masked_from) * 1.001
+    ref = np.linalg.inv(np.linalg.cholesky(k))
+    with jax.enable_x64():
+        got = np.asarray(tril_inverse(cholesky(jnp.asarray(k))))
+    np.testing.assert_allclose(got, ref, rtol=1e-12, atol=1e-12)
+    assert np.all(np.triu(got, 1) == 0.0)
+    np.testing.assert_array_equal(
+        got[masked_from:, masked_from:],
+        np.eye(16 - masked_from) / np.sqrt(1.001))
+
+
 def test_batched_ei_matches_numpy():
     import jax.numpy as jnp
 

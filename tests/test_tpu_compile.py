@@ -9,6 +9,7 @@ compiles in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,7 +131,7 @@ def test_sharded_f64_gp_fit_compiles_for_v5e(topo):
     rows = NamedSharding(mesh, PartitionSpec("lanes"))
 
     def fit(x, y, mask):
-        return jax.vmap(lambda *a: gp_fit(*a).chol)(x, y, mask)
+        return jax.vmap(lambda *a: gp_fit(*a).l_inv)(x, y, mask)
 
     sharded = shard_map_1d(fit, mesh, in_specs=(PartitionSpec("lanes"),) * 3,
                            out_specs=PartitionSpec("lanes"))
@@ -142,3 +143,58 @@ def test_sharded_f64_gp_fit_compiles_for_v5e(topo):
                 jax.ShapeDtypeStruct((lanes, slots), jnp.bool_,
                                      sharding=rows))
         jax.jit(sharded).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["tables", "seeded"])
+def test_replay_scan_is_loop_free_for_v5e(one_chip, program):
+    """The replay's scanned BO round, at the 4,096-lane bucket of the
+    benchmark's widest matrix, for one chip: the scan is the program's
+    only ``while``, and no float64 contraction reaches the compiler.
+    The TPU emulates a float64 dot or triangular solve as a loop of
+    bfloat16 passes, so one ``@`` in ``optimizer.gp`` or
+    ``optimizer.acquire`` shows here as a nested loop."""
+    from repro.common.rng import x64_streams
+    from repro.optimizer.replay import (ReplayConfig, _replay_fn,
+                                        _seeded_replay_fn)
+
+    cfg = ReplayConfig()
+    lanes, slots, n_cand, dim, base_dim = 4096, 16, 69, 10, 6
+    n_workloads, n_conds = 18, 2
+    rounds = cfg.max_runs - cfg.n_init
+
+    def sds(shape, dtype=jnp.float64):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with x64_streams():
+        carry = (sds((lanes, cfg.max_runs), jnp.int32),
+                 sds((lanes,), jnp.int32), sds((lanes,), jnp.bool_))
+        if program == "tables":
+            fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds)
+            args = (carry, (
+                sds((lanes, n_cand, dim)), sds((lanes, n_cand, dim)),
+                sds((lanes, n_cand)), sds((lanes, n_cand)),
+                sds((lanes, n_cand, 4)), sds((lanes, n_cand, 4)),
+                sds((lanes, n_cand)), sds((lanes,)),
+                sds((lanes,), jnp.bool_)))
+        else:
+            fn = _seeded_replay_fn(cfg, lanes, slots, n_cand, base_dim,
+                                   rounds, n_workloads, n_conds, 0.1)
+            args = (carry, (
+                sds((lanes,), jnp.int32), sds((lanes,), jnp.int32),
+                sds((lanes,), jnp.int32), sds((lanes,))), (
+                sds((n_workloads, n_cand)), sds((n_workloads, n_cand, 4)),
+                sds((4,)), sds((n_cand, base_dim)), sds((n_cand,)),
+                sds((n_cand,)), sds((n_cand,), jnp.int32),
+                sds((n_conds, n_cand, 4)), sds((n_conds, n_cand, 4)),
+                sds((2,), jnp.uint32)))
+        lowered = fn.lower(*args)
+        f64_contractions = [
+            line for line in lowered.as_text().splitlines()
+            if re.search(r"dot_general|convolution|triangular_solve",
+                         line) and "f64" in line]
+        assert f64_contractions == []
+        compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", hlo)) == 1
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < V5E_HBM_BYTES
