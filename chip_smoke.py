@@ -25,12 +25,17 @@ One chip, in order:
    against ``kernels/edge_softmax/ref.py`` on the CPU;
 5. replay — the §IV-D 432-lane matrix through
    ``optimizer.replay_scenarios``, lane-for-lane trace parity with the
-   sequential ``reference_search``.
+   sequential ``reference_search``; then Karasu lanes (every workload x
+   KARASU_SEEDS x {karasu, karasu+perona} x both conditions, 17 RGPE
+   support models each from ``support_history``) through the same
+   entry, trace parity with ``tuning.karasu``.
 
 Four chips run only what exists across chips, each against one device
 of the same host, and require bit-identical results: the
 ``ShardedScorer`` over a 4-device ``"fleet"`` mesh, and the replay with
-its lane axis sharded over 4 devices.
+its lane axis sharded over 4 devices, CherryPick/Arrow lanes and
+Karasu lanes (bit-identical to one device at the same per-device lane
+count; see ``phase_sharded_karasu``).
 
 Any failed check raises. The last line of standard output is a JSON
 object ``{"ok": true, "device": {...}}`` and is printed only when every
@@ -70,6 +75,8 @@ KERNEL_TOL = 1e-5
 KERNEL_SHAPE = dict(n=4096, heads=4, head_dim=8, preds=3)
 #: The §IV-D matrix: 18 workloads x 3 seeds x 4 variants x 2 conditions.
 REPLAY_SEEDS = (0, 1, 2)
+#: search seeds of the Karasu lanes (72 lanes, padded to 128)
+KARASU_SEEDS = (0,)
 
 
 def _say(tag: str, msg: str) -> None:
@@ -283,6 +290,40 @@ def phase_replay() -> None:
     check(same == len(scens), f"trace parity {same}/{len(scens)}")
 
 
+def karasu_matrix():
+    """Karasu lanes over the §IV-D shape, with the support history
+    they borrow from (8 past CherryPick searches a workload)."""
+    from benchmarks.bench_optimizer import _conditions, _profile_scores
+    from repro.optimizer import build_scenarios, support_history
+    from repro.tuning.scout import VM_TYPES, ScoutDataset
+
+    ds = ScoutDataset(seed=SEED)
+    scores = _profile_scores(VM_TYPES)
+    hist = support_history(ds, scores)
+    scens = build_scenarios(ds, seeds=KARASU_SEEDS,
+                            variants=("karasu", "karasu+perona"),
+                            conditions=_conditions())
+    return ds, scens, scores, hist
+
+
+def phase_karasu() -> None:
+    from repro.optimizer import reference_search, replay_scenarios
+
+    ds, scens, scores, hist = karasu_matrix()
+    t0 = time.perf_counter()
+    traces = replay_scenarios(ds, scens, scores, support=hist)
+    wall = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    refs = [reference_search(ds, sc, scores, support=hist) for sc in scens]
+    t_ref = time.perf_counter() - t0
+    same = sum(_same_trace(a, b) for a, b in zip(refs, traces))
+    _say("karasu", f"{len(scens)} lanes x {hist.n_support} support "
+                   f"models: trace parity {same}/{len(scens)} vs "
+                   f"tuning.karasu; replay wall {wall:.1f}s incl. "
+                   f"compile, sequential reference {t_ref:.1f}s")
+    check(same == len(scens), f"Karasu trace parity {same}/{len(scens)}")
+
+
 def _same_trace(a, b) -> bool:
     return ([c.key for c in a.evaluated] == [c.key for c in b.evaluated]
             and a.best_valid_cost == b.best_valid_cost
@@ -403,6 +444,54 @@ def phase_sharded_replay(devices) -> None:
     check(same == len(scens), "sharded replay diverged")
 
 
+def phase_sharded_karasu(devices) -> None:
+    """The Karasu lanes with the lane axis sharded over ``devices``
+    (support grid replicated) against one device. The float64 EI's
+    last bits depend on how many lanes one device's program holds, so
+    the first device's lanes must match the single-device program at
+    that per-device lane count bit for bit (picks, counts, every
+    round's peak EI), and every lane must pick as the single-device
+    program over the whole matrix, with peaks within the Karasu
+    cell's ``ei_peak_rtol``."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.common.mesh import shard_size
+    from repro.optimizer import ReplayConfig, lane_tables, replay
+
+    cfg = ReplayConfig()
+    ds, scens, scores, hist = karasu_matrix()
+    tab = lane_tables(ds, scens, scores, cfg, hist)
+    single = replay(tab, cfg)
+    sharded = replay(tab, cfg, devices=devices)
+    per_dev = shard_size(len(tab), len(devices)) // len(devices)
+    head = dataclasses.replace(tab, **{
+        f.name: getattr(tab, f.name)[:per_dev]
+        for f in dataclasses.fields(tab)
+        if isinstance(getattr(tab, f.name), np.ndarray)})
+    first = replay(head, cfg, lanes_floor=per_dev)
+    n = len(first.count)
+    same = int(np.sum(np.all(sharded.chosen == single.chosen, axis=1)
+                      & (sharded.count == single.count)))
+    first_bits = (np.array_equal(sharded.chosen[:n], first.chosen)
+                  and np.array_equal(sharded.count[:n], first.count)
+                  and np.array_equal(sharded.peaks[:n], first.peaks,
+                                     equal_nan=True))
+    gap = float(np.nanmax(np.abs(sharded.peaks - single.peaks)
+                          / np.maximum(np.abs(single.peaks), 1e-12),
+                          initial=0.0))
+    _say("4chip", f"Karasu replay on {len(devices)} devices vs 1: "
+                  f"{same}/{len(scens)} lanes with identical picks and "
+                  f"counts, largest relative peak EI gap {gap:.3e}; the "
+                  f"first device's {n} lanes bit-identical to one "
+                  f"device at {per_dev} lanes: {first_bits}")
+    check(same == len(scens), "sharded Karasu replay diverged")
+    check(first_bits, "sharded Karasu lanes differ from one device "
+                      "at the same lane count")
+    check(gap <= 1e-7, f"sharded Karasu peak EI gap {gap}")
+
+
 # ------------------------------------------------------------------- main
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -427,10 +516,12 @@ def main() -> None:
         devices = jax.devices()[:4]
         phase_sharded_scorer(devices, NODES, SEED)
         phase_sharded_replay(devices)
+        phase_sharded_karasu(devices)
     else:
         phase_serve(NODES, ROUNDS, SEED)
         phase_kernel(SEED)
         phase_replay()
+        phase_karasu()
     _say("done", f"all phases passed in {time.perf_counter() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": device}), flush=True)
 

@@ -25,7 +25,7 @@ import numpy as np
 
 from repro.optimizer import (HEALTHY, build_scenarios, drifted_condition,
                              replay_pipelined)
-from repro.optimizer.scenarios import VARIANTS
+from repro.optimizer.scenarios import SOLO_VARIANTS as VARIANTS
 from repro.tuning.perona_weights import fingerprint_machine_scores
 from repro.tuning.scout import VM_TYPES, ScoutDataset, WORKLOAD_NAMES
 

@@ -43,6 +43,9 @@ STREAM_CONTENTION = 32  # scout per-(workload, config) contention noise
 STREAM_ARRIVALS = 33  # fleet telemetry arrival-process jitter
 STREAM_FAULTS = 34  # fleet fault-injection decisions (fleet.faults)
 STREAM_RETRY = 35  # scorer retry-backoff jitter (fleet.service)
+STREAM_SUPPORT = 36  # Karasu support history: its search seeds and
+#                      each lane's choice of support searches
+STREAM_RGPE = 37  # RGPE posterior samples (optimizer.replay, tuning.karasu)
 
 
 def root_key(seed: int):
@@ -92,6 +95,25 @@ def lognormal_noise_row(key_stream, wid, uids, scale):
         return jnp.exp(scale * jax.random.normal(k, (), jnp.float64))
 
     return jax.vmap(cell)(uids)
+
+
+def normal_block(key_stream, a, b, shape):
+    """Standard normals of ``shape`` drawn from
+    ``fold_in(fold_in(key_stream, a), b)``: the RGPE samples of a
+    search with seed ``a`` at its round with ``b`` observations.
+
+    Drawn in float32 and widened to float64, which holds them exactly:
+    a sample needs no more bits, and the TPU computes float32's
+    ``erf_inv`` natively where it emulates float64's, which would make
+    the draw most of the replay scan's time and memory. Pure jnp like
+    :func:`lognormal_noise_row`: eager on the host (the sequential
+    reference) and vmapped inside the compiled replay program, with
+    the same bits; call it under :func:`x64_streams`."""
+    import jax
+    import jax.numpy as jnp
+
+    key = jax.random.fold_in(jax.random.fold_in(key_stream, a), b)
+    return jax.random.normal(key, shape, jnp.float32).astype(jnp.float64)
 
 
 def lognormal_noise_grid(key_stream, n_workloads: int,

@@ -6,8 +6,9 @@ configuration searches as parallel vmapped lanes on device:
 
 - :mod:`repro.optimizer.gp` — batched masked RBF GP (fit + predict as
   pure jnp ops, pinned against ``tuning/gp.py``);
-- :mod:`repro.optimizer.acquire` — expected improvement and the §IV-D
-  Perona acquisition weighting as pure array ops;
+- :mod:`repro.optimizer.acquire` — expected improvement, the §IV-D
+  Perona acquisition weighting and the RGPE ensemble of Karasu lanes
+  (ranking losses, weights, mixed predictive) as pure array ops;
 - :mod:`repro.optimizer.replay` — full BO search loops as one
   ``lax.scan`` over rounds, every lane advanced per round; the lane
   axis optionally sharded over a 1-D device mesh (``common.mesh``),
@@ -20,7 +21,9 @@ configuration searches as parallel vmapped lanes on device:
   path (``lane_spec`` / ``replay_seeded``) ships only the compact
   deterministic grid + per-lane ids and re-derives every stochastic
   table cell inside the compiled program from counter-based
-  ``fold_in`` keys — bit-identical to the host tables.
+  ``fold_in`` keys — bit-identical to the host tables. Karasu lanes
+  (``support_history``: other workloads' past searches as RGPE support
+  models) run on the host-table path.
 """
 
 from repro.optimizer.replay import (REPLAY_TRACES, BatchReplayResult,
@@ -30,8 +33,10 @@ from repro.optimizer.replay import (REPLAY_TRACES, BatchReplayResult,
                                     replay_seeded_async,
                                     traces_from_result,
                                     traces_from_spec)
-from repro.optimizer.scenarios import (HEALTHY, DeferredFleetCondition,
+from repro.optimizer.scenarios import (HEALTHY, SOLO_VARIANTS, VARIANTS,
+                                       DeferredFleetCondition,
                                        FleetCondition, Scenario,
+                                       SupportHistory,
                                        build_scenarios,
                                        condition_from_drift,
                                        degrade_scores, drifted_condition,
@@ -40,16 +45,18 @@ from repro.optimizer.scenarios import (HEALTHY, DeferredFleetCondition,
                                        replay_pipelined,
                                        replay_scenarios,
                                        resolve_condition,
-                                       simulate_degraded_fleet)
+                                       simulate_degraded_fleet,
+                                       support_history)
 
 __all__ = [
     "REPLAY_TRACES", "BatchReplayResult", "PendingReplay",
     "ReplayConfig", "SeededLaneSpec", "replay", "replay_async",
     "replay_seeded", "replay_seeded_async", "traces_from_result",
     "traces_from_spec",
-    "HEALTHY", "DeferredFleetCondition", "FleetCondition", "Scenario",
+    "HEALTHY", "SOLO_VARIANTS", "VARIANTS", "DeferredFleetCondition",
+    "FleetCondition", "Scenario", "SupportHistory",
     "build_scenarios", "condition_from_drift", "degrade_scores",
     "drifted_condition", "lane_spec", "lane_tables",
     "reference_search", "replay_pipelined", "replay_scenarios",
-    "resolve_condition", "simulate_degraded_fleet",
+    "resolve_condition", "simulate_degraded_fleet", "support_history",
 ]

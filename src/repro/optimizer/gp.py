@@ -178,3 +178,45 @@ def gp_predict(state: GPState, xs: jnp.ndarray):
 def gp_fit_predict(x, y, mask, xs, noise: float = 1e-3):
     """Convenience fused fit+predict (one lane); vmap for batches."""
     return gp_predict(gp_fit(x, y, mask, noise), xs)
+
+
+def gp_joint_posterior(state: GPState, xs: jnp.ndarray):
+    """Joint posterior of the latent function at ``xs`` (C, D), in the
+    fit's standardized units: the mean (C,) and the full covariance
+    (C, C) = K(xs, xs) - W^T W with W = L^-1 k*^T. This is what a
+    Karasu support model carries (``optimizer.scenarios.
+    support_history``); its diagonal is :func:`gp_predict`'s variance
+    before scaling."""
+    ks = _kernel(xs, state.x, state.scales) * state.mask[None, :]
+    mean = jnp.sum(ks * state.alpha, axis=-1)
+    w = jnp.sum(state.l_inv[:, None, :] * ks[None, :, :], axis=-1)
+    cov = (_kernel(xs, xs, state.scales)
+           - jnp.sum(w[:, :, None] * w[:, None, :], axis=0))
+    return mean, cov
+
+
+def loo_posterior(state: GPState, y: jnp.ndarray):
+    """Leave-one-out predictive (mean, sd) at each observation, in
+    standardized units, in closed form from K^-1 (Rasmussen and
+    Williams, eq. 5.12): mu_-j = yn_j - [K^-1 yn]_j / [K^-1]_jj and
+    var_-j = 1 / [K^-1]_jj, with K^-1 = L^-T L^-1 so that
+    [K^-1]_jj is the squared norm of column j of L^-1. ``y`` is the
+    padded observation vector the state was fitted on; masked slots
+    give values no caller reads."""
+    yn = jnp.where(state.mask, (y - state.y_mean) / state.y_std, 0.0)
+    kinv_diag = jnp.sum(state.l_inv * state.l_inv, axis=0)
+    return yn - state.alpha / kinv_diag, jnp.sqrt(1.0 / kinv_diag)
+
+
+def joint_samples(mean: jnp.ndarray, cov: jnp.ndarray, mask: jnp.ndarray,
+                  z: jnp.ndarray, jitter: float) -> jnp.ndarray:
+    """Joint samples ``mean + L z`` (S, R) of one model at R padded
+    points from standard normals ``z`` (S, R), L the Cholesky factor
+    of ``cov`` + ``jitter`` I over the valid points (masked points get
+    an identity block, so the valid prefix's samples depend on the
+    valid prefix of ``z`` alone)."""
+    pmask = mask[:, None] & mask[None, :]
+    k = jnp.where(pmask, cov, 0.0) + jnp.diag(
+        jnp.where(mask, jitter, 1.0))
+    chol = cholesky(k)
+    return mean + jnp.sum(chol[None, :, :] * z[:, None, :], axis=-1)

@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.common.mesh import (axis_specs, build_mesh, pad_lanes,
                                pow2_devices, shard_map_1d, shard_size)
+from repro.common.rng import x64_streams
 from repro.obs import trace as obs_trace
 from repro.obs.jaxstat import JitSite
 
@@ -71,6 +72,25 @@ class ReplayConfig:
     xi: float = 0.01
     strength: float = 0.3
     per_dollar: bool = True
+    #: RGPE posterior samples per model and round (Karasu lanes only)
+    samples: int = 256
+
+
+#: Diagonal jitter of a support model's posterior covariance at the
+#: target's observations before its Cholesky factor (standardized
+#: units; numerical, far below the GP's 1e-3 noise).
+RGPE_JITTER = 1e-6
+
+
+@dataclasses.dataclass
+class SupportGrid:
+    """Posteriors of the support models of Karasu lanes over the
+    shared candidate grid, one row per past search, in each search's
+    standardized units (``optimizer.scenarios.support_history``)."""
+
+    mean: np.ndarray  # (N, C) posterior mean
+    var: np.ndarray  # (N, C) posterior variance, floored like gp_predict
+    cov: np.ndarray  # (N, C, C) joint posterior covariance
 
 
 @dataclasses.dataclass
@@ -91,9 +111,20 @@ class LaneTables:
     util_low: np.ndarray  # (L, C, 4) per-run utilization metrics
     use_weighter: np.ndarray  # (L,) Perona-weighted lane flag
     init_idx: np.ndarray  # (L, n_init) seeded init draws
+    # Karasu lanes (RGPE over support models); absent for a matrix
+    # without them, which then runs today's program unchanged
+    support_ids: Optional[np.ndarray] = None  # (L, M) rows of
+    #                      support_grid, -1 an empty support slot
+    search_seed: Optional[np.ndarray] = None  # (L,) RGPE sample stream
+    support_grid: Optional[SupportGrid] = None
 
     def __len__(self) -> int:
         return len(self.y)
+
+    @property
+    def n_support(self) -> int:
+        """Support slots per lane, M (0: no Karasu lane)."""
+        return 0 if self.support_ids is None else self.support_ids.shape[1]
 
 
 @dataclasses.dataclass
@@ -101,6 +132,9 @@ class BatchReplayResult:
     chosen: np.ndarray  # (L, max_runs) evaluated config indices, -1 pad
     count: np.ndarray  # (L,) evaluations performed per lane
     dispatches: int  # device dispatches of this replay (always 1)
+    #: (L, rounds) each round's peak EI (see _lane_step); Karasu
+    #: programs only
+    peaks: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -147,8 +181,17 @@ class SeededLaneSpec:
 
 
 def _lane_step(sel, count, active, xt, xc, y_tab, r_tab, ulow, ns,
-               price, limit, use_w, *, cfg: ReplayConfig, slots: int):
-    """One BO round of one lane (vmapped over lanes by the caller)."""
+               price, limit, use_w, *, cfg: ReplayConfig, slots: int,
+               support=None):
+    """One BO round of one lane (vmapped over lanes by the caller).
+
+    ``support`` (Karasu lanes): ``(ids, seed, grid, key)``, the lane's
+    M support rows (-1 empty), its search seed, the replicated
+    :class:`SupportGrid` arrays and the RGPE stream key; see
+    :func:`_rgpe_predict`. Such a step also returns the round's peak:
+    the largest float64 EI over the unseen candidates before the
+    float32 selection grid (NaN once the lane has stopped), the number
+    the Karasu cell's check compares with its reference."""
     import jax.numpy as jnp
 
     from repro.optimizer.acquire import (expected_improvement,
@@ -167,6 +210,9 @@ def _lane_step(sel, count, active, xt, xc, y_tab, r_tab, ulow, ns,
     state = gp_fit(x_obs, y_obs, mask_p, noise=cfg.noise,
                    median_rows=cfg.max_runs)
     mu, sigma = gp_predict(state, xc)
+    if support is not None:
+        mu, sigma = _rgpe_predict(state, y_obs, idx, count, mu, sigma,
+                                  *support, cfg=cfg)
     best = jnp.min(jnp.where(mask_p, y_obs, jnp.inf))
     ei = expected_improvement(mu, sigma, best, xi=cfg.xi)
 
@@ -181,6 +227,7 @@ def _lane_step(sel, count, active, xt, xc, y_tab, r_tab, ulow, ns,
     seen = jnp.zeros(n_cand, jnp.int32).at[idx].add(
         omask.astype(jnp.int32)) > 0
     ei = jnp.where(seen, -jnp.inf, ei)
+    peak = jnp.max(ei)
     # float32-rounded selection grid, shared with the sequential
     # reference (see CherryPick.search): deterministic tie-breaks on
     # ulp-close candidates regardless of backend rounding
@@ -194,7 +241,56 @@ def _lane_step(sel, count, active, xt, xc, y_tab, r_tab, ulow, ns,
     pick = jnp.argmax(ei).astype(sel.dtype)
     sel = sel.at[count].set(jnp.where(advance, pick, sel[count]))
     count = count + advance.astype(count.dtype)
+    if support is not None:
+        return sel, count, advance, jnp.where(active, peak, jnp.nan)
     return sel, count, advance
+
+
+def _rgpe_predict(state, y_obs, idx, count, mu, sigma, ids, seed, grid,
+                  key, *, cfg: ReplayConfig):
+    """The RGPE ensemble's predictive at the candidates for one lane
+    and round (arXiv 1802.02219, sections 3-4; ``tuning.karasu`` is
+    the sequential reference).
+
+    Over the R = ``max_runs`` observation slots (valid ones first):
+    S joint samples of each support model at the target's observed
+    configurations, the target's own from its leave-one-out
+    posteriors, all drawn from ``fold_in(fold_in(key, seed), count)``;
+    their ranking losses give the weights
+    (:func:`acquire.rgpe_weights`), which mix the target's and the
+    support models' posteriors (:func:`acquire.ensemble_predict`).
+    With every support slot empty the target holds all the weight and
+    the lane is a CherryPick lane, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.common.rng import normal_block
+    from repro.optimizer.acquire import (ensemble_predict, ranking_losses,
+                                         rgpe_weights)
+    from repro.optimizer.gp import joint_samples, loo_posterior
+
+    mean_g, var_g, cov_g = grid
+    r = cfg.max_runs
+    m = ids.shape[0]
+    n_cand = mean_g.shape[1]
+    mask = jnp.arange(r) < count
+    z = normal_block(key, seed, count, (m + 1, cfg.samples, r))
+    mu_loo, sd_loo = loo_posterior(state, y_obs)
+    f_t = mu_loo[:r] + sd_loo[:r] * z[0]
+    row = jnp.maximum(ids, 0)
+    # gather only the (M, R, R) block each round, never a (M, C, C) one
+    flat = ((row[:, None, None] * n_cand + idx[None, :, None]) * n_cand
+            + idx[None, None, :])
+    cov_o = cov_g.reshape(-1)[flat]
+    mean_o = mean_g[row[:, None], idx[None, :]]
+    f_s = jax.vmap(joint_samples, in_axes=(0, 0, None, 0, None))(
+        mean_o, cov_o, mask, z[1:], RGPE_JITTER)
+    losses = ranking_losses(jnp.concatenate([f_t[None], f_s]), y_obs[:r],
+                            mask)
+    eligible = jnp.concatenate([jnp.ones(1, bool), ids >= 0])
+    w = rgpe_weights(losses, eligible, r * (r - 1))
+    return ensemble_predict(w, mu, sigma, state.y_mean, state.y_std,
+                            mean_g[row], var_g[row])
 
 
 #: Number of stacked lane-table arrays a replay dispatch consumes.
@@ -217,37 +313,61 @@ _COMPILE_LOCK = threading.Lock()
 @functools.lru_cache(maxsize=32)
 def _replay_fn(cfg: ReplayConfig, lanes: int, slots: int, n_cand: int,
                dim: int, rounds: int,
-               devices: Optional[Tuple] = None):
+               devices: Optional[Tuple] = None, support: int = 0):
     """Jitted scan program for one (config, shape, mesh) signature.
 
     ``devices=None`` is the single-device program. A device tuple
     shards the lane axis: each device scans its own
     ``lanes/len(devices)`` lane bucket (``shard_map`` around the
     vmapped step), one dispatch total.
+
+    ``support`` is the static count M of support slots per lane. With
+    M > 0 the program takes two more argument groups, the lanes'
+    ``(support_ids, search_seed)`` and the replicated
+    :class:`SupportGrid` arrays, and every lane runs the RGPE ensemble
+    (a lane whose slots are all empty reduces to CherryPick); M = 0
+    traces today's program exactly.
     """
     import jax
 
-    step = functools.partial(_lane_step, cfg=cfg, slots=slots)
-    step_v = jax.vmap(step)
+    if support:
+        from repro.common.rng import STREAM_RGPE, stream_key
 
-    def run(carry, tables):
+        key = stream_key(0, STREAM_RGPE)
+
+    def run(carry, tables, lane_support=(), grid=()):
         REPLAY_TRACES.tick()
+        if support:
+            def lane_step(sel, count, active, *a):
+                *tabs, ids, seed = a
+                return _lane_step(sel, count, active, *tabs, cfg=cfg,
+                                  slots=slots,
+                                  support=(ids, seed, grid, key))
+        else:
+            lane_step = functools.partial(_lane_step, cfg=cfg,
+                                          slots=slots)
+        step_v = jax.vmap(lane_step)
 
         def scan_step(c, _):
-            sel, count, active = c
-            sel, count, active = step_v(sel, count, active, *tables)
-            return (sel, count, active), None
+            sel, count, active, *peak = step_v(*c, *tables, *lane_support)
+            return (sel, count, active), (peak[0] if peak else None)
 
-        (sel, count, _), _ = jax.lax.scan(scan_step, carry, None,
-                                          length=rounds)
+        (sel, count, _), peaks = jax.lax.scan(scan_step, carry, None,
+                                              length=rounds)
+        if support:
+            return sel, count, peaks.T
         return sel, count
 
     if devices is not None and len(devices) > 1:
         mesh = build_mesh("lanes", devices)
         lane = axis_specs("lanes", 1)[0]
-        run = shard_map_1d(run, mesh,
-                           in_specs=((lane,) * 3, (lane,) * N_TABLES),
-                           out_specs=(lane, lane))
+        in_specs = ((lane,) * 3, (lane,) * N_TABLES)
+        out_specs = (lane, lane)
+        if support:
+            in_specs += ((lane,) * 2, axis_specs("lanes", 0, 3))
+            out_specs += (lane,)
+        run = shard_map_1d(run, mesh, in_specs=in_specs,
+                           out_specs=out_specs)
     return jax.jit(run, donate_argnums=(0,))
 
 
@@ -369,6 +489,31 @@ def _staging(n_lanes: int, padded: int):
         yield put
 
 
+def _stage_support(put, to_dev, to_all, tables: LaneTables, pad):
+    """The ``replay.support_tables`` span (inside ``replay.stage``):
+    pad and cast the lanes' support ids and search seeds, and place
+    them with the replicated :class:`SupportGrid`; its ``bytes`` arg
+    counts what was placed, ``models`` the support slots per lane."""
+    grid = tables.support_grid
+    if grid is None:
+        raise ValueError("Karasu lanes need the support grid their "
+                         "support ids index")
+    args = {"models": tables.n_support, "bytes": 0}
+
+    def put_counted(place, a):
+        args["bytes"] += a.nbytes
+        return put(place, a)
+
+    with obs_trace.span("replay.support_tables", args=args):
+        lane = tuple(put_counted(to_dev, pad(a)) for a in (
+            tables.support_ids.astype(np.int32, copy=False),
+            tables.search_seed.astype(np.uint32, copy=False)))
+        shared = tuple(put_counted(to_all, a.astype(np.float64,
+                                                    copy=False))
+                       for a in (grid.mean, grid.var, grid.cov))
+    return lane, shared
+
+
 def _initial_carry(put, to_dev, init_idx, cfg: ReplayConfig,
                    lanes: int):
     """Placed scan carry ``(sel, count, active)``: every lane starts
@@ -390,14 +535,17 @@ class PendingReplay:
     dispatches: int
     _sel: object
     _count: object
+    _peaks: object = None
 
     def result(self) -> BatchReplayResult:
         with obs_trace.span("replay.wait", cat=obs_trace.CAT_DEVICE,
                             args={"lanes": self.n_lanes}):
             sel = np.asarray(self._sel)[: self.n_lanes]
             count = np.asarray(self._count)[: self.n_lanes]
+            peaks = (None if self._peaks is None
+                     else np.asarray(self._peaks)[: self.n_lanes])
         return BatchReplayResult(chosen=sel, count=count,
-                                 dispatches=self.dispatches)
+                                 dispatches=self.dispatches, peaks=peaks)
 
 
 def replay_async(tables: LaneTables,
@@ -444,10 +592,15 @@ def replay_async(tables: LaneTables,
 
     from repro.serving.engine import silence_unusable_donation
 
-    fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, devs)
-    to_dev, _ = _placement(device, devs)
+    n_support = tables.n_support
+    fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, devs,
+                    n_support)
+    to_dev, to_all = _placement(device, devs)
+    extra = ()
+    # Karasu lanes draw RGPE samples: threefry's pinned derivation
+    streams = x64_streams if n_support else jax.enable_x64
 
-    with jax.enable_x64(), silence_unusable_donation():
+    with streams(), silence_unusable_donation():
         with _staging(n_lanes, lanes) as put:
             # copy=False: lane_tables already builds f64 columns, so
             # the dtype casts are no-ops for the common path
@@ -464,21 +617,24 @@ def replay_async(tables: LaneTables,
                     tables.use_weighter.astype(bool, copy=False)))
             carry0 = _initial_carry(put, to_dev, pad(tables.init_idx),
                                     cfg, lanes)
+            if n_support:
+                extra = _stage_support(put, to_dev, to_all, tables, pad)
         # keyed on placement too: each device's first call compiles
         # its own executable and must take the serialized branch
-        sig = (cfg, lanes, slots, n_cand, dim, rounds, devs, device)
-        with REPLAY_TRACES.dispatch(
-                "replay.dispatch",
-                args={"lanes": n_lanes, "padded": lanes,
-                      "rounds": rounds}):
+        sig = (cfg, lanes, slots, n_cand, dim, rounds, devs, device,
+               n_support)
+        args = {"lanes": n_lanes, "padded": lanes, "rounds": rounds}
+        if n_support:
+            args.update(support_models=n_support,
+                        posterior_samples=cfg.samples)
+        with REPLAY_TRACES.dispatch("replay.dispatch", args=args):
             if sig in _COMPILED_SIGNATURES:
-                sel, count = fn(carry0, jnp_tables)
+                outs = fn(carry0, jnp_tables, *extra)
             else:
                 with _COMPILE_LOCK:
-                    sel, count = fn(carry0, jnp_tables)
+                    outs = fn(carry0, jnp_tables, *extra)
                     _COMPILED_SIGNATURES.add(sig)
-    return PendingReplay(n_lanes=n_lanes, dispatches=1,
-                         _sel=sel, _count=count)
+    return PendingReplay(n_lanes, 1, *outs)
 
 
 def replay(tables: LaneTables,
@@ -503,8 +659,6 @@ def replay_seeded_async(spec: SeededLaneSpec,
 
     The condition axis is pow2-padded so matrices with different
     condition counts reuse one compiled program."""
-    from repro.common.rng import x64_streams
-
     cfg = ReplayConfig() if cfg is None else cfg
     if devices is not None and device is not None:
         raise ValueError("pass either devices= (shard_map) or "

@@ -28,17 +28,24 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.common.bucketing import next_pow2
+from repro.common.rng import STREAM_SUPPORT, folded_generator
 from repro.core.ranking import machine_score_matrix, \
     machine_score_vector
 from repro.obs import trace as obs_trace
 from repro.optimizer.replay import (LaneTables, ReplayConfig,
-                                    SeededLaneSpec, replay,
+                                    SeededLaneSpec, SupportGrid, replay,
                                     replay_async, replay_seeded_async,
                                     traces_from_result,
                                     traces_from_spec)
 from repro.tuning.scout import LOW_CAPS, PRICES, ScoutDataset
 
-VARIANTS = ("cherrypick", "cherrypick+perona", "arrow", "arrow+perona")
+#: Tuner variants, indexed by ``variant_id``: new ones are appended.
+#: ``karasu`` is CherryPick with the RGPE ensemble of support models
+#: (Karasu, arXiv 2308.11792), ``karasu+perona`` adds the weighting.
+VARIANTS = ("cherrypick", "cherrypick+perona", "arrow", "arrow+perona",
+            "karasu", "karasu+perona")
+#: The variants that borrow nothing from other tenants' searches.
+SOLO_VARIANTS = VARIANTS[:4]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -213,7 +220,7 @@ class Scenario:
 def build_scenarios(ds: ScoutDataset, *,
                     workloads: Optional[Sequence[str]] = None,
                     seeds: Sequence[int] = (0,),
-                    variants: Sequence[str] = VARIANTS,
+                    variants: Sequence[str] = SOLO_VARIANTS,
                     conditions: Sequence[FleetCondition] = (HEALTHY,),
                     limit_percentile: float = 40.0,
                     condition_major: bool = False) -> List[Scenario]:
@@ -246,17 +253,131 @@ def build_scenarios(ds: ScoutDataset, *,
     return matrix
 
 
+@dataclasses.dataclass
+class SupportHistory:
+    """Karasu's shared profiling history (arXiv 2308.11792): past
+    CherryPick searches of every workload on the healthy fleet, which
+    other tenants' searches borrow as RGPE support models. Row
+    ``w * searches + h`` is the ``h``-th past search of
+    ``workloads[w]``; ``grid`` holds each one's support model, a GP on
+    its standardized observations, as a joint posterior over the
+    candidate grid."""
+
+    workloads: Tuple[str, ...]
+    seeds: np.ndarray  # (H,) the past searches' seeds, per workload
+    limits: np.ndarray  # (N,) runtime limit of each past search
+    traces: List  # (N,) the past searches (tuning SearchTrace)
+    grid: SupportGrid
+
+    @property
+    def searches(self) -> int:
+        return len(self.seeds)
+
+    @property
+    def n_support(self) -> int:
+        """Support slots per lane, M: one past search of each other
+        workload."""
+        return len(self.workloads) - 1
+
+    def seed_ids(self, seed: int) -> np.ndarray:
+        """(W, M) support rows of a search with ``seed``, one row per
+        target workload: for each other workload, in order, the past
+        search that the seed's stream picks."""
+        n = len(self.workloads)
+        pick = folded_generator(STREAM_SUPPORT, 1, seed).integers(
+            0, self.searches, n)
+        rows = np.arange(n) * self.searches + pick
+        others = ~np.eye(n, dtype=bool)
+        return np.tile(rows, (n, 1))[others].reshape(n, n - 1).astype(
+            np.int32)
+
+    def lane_ids(self, workload: str, seed: int) -> np.ndarray:
+        """The M support rows of a search of ``workload`` with
+        ``seed`` (see :meth:`seed_ids`)."""
+        if workload not in self.workloads:
+            raise ValueError(f"{workload!r} has no support history")
+        return self.seed_ids(seed)[self.workloads.index(workload)]
+
+
+def support_seeds(dataset_seed: int, searches: int) -> np.ndarray:
+    """Seeds of the past searches in a support history: the same
+    ``searches`` seeds for every workload."""
+    return folded_generator(STREAM_SUPPORT, 0, dataset_seed).integers(
+        0, 2**31 - 1, searches)
+
+
+def support_history(ds: ScoutDataset,
+                    machine_scores: Dict[str, Dict[str, float]], *,
+                    workloads: Optional[Sequence[str]] = None,
+                    searches: int = 8,
+                    cfg: Optional[ReplayConfig] = None,
+                    limit_percentile: float = 40.0) -> SupportHistory:
+    """Build Karasu's support history: ``searches`` CherryPick
+    searches per workload on the healthy fleet, replayed through
+    :func:`replay_scenarios` itself, then one GP per search on its
+    standardized, constraint-penalized observations (CherryPick's
+    features, median-heuristic scales, ``cfg.noise``) and its joint
+    posterior over every candidate."""
+    import jax
+
+    from repro.optimizer.gp import gp_fit, gp_joint_posterior
+
+    cfg = ReplayConfig() if cfg is None else cfg
+    workloads = list(ds.workloads) if workloads is None else workloads
+    seeds = support_seeds(ds.seed, searches)
+    scens = build_scenarios(ds, workloads=workloads,
+                            seeds=[int(x) for x in seeds],
+                            variants=("cherrypick",),
+                            conditions=(HEALTHY,),
+                            limit_percentile=limit_percentile)
+    traces = replay_scenarios(ds, scens, machine_scores, cfg)
+
+    col = {c.key: j for j, c in enumerate(ds.configs)}
+    x_base = np.stack([ds.config_features(c) for c in ds.configs])
+    x_cand = np.concatenate([x_base, np.zeros((len(x_base), 4))], 1)
+    slots = next_pow2(cfg.max_runs)
+    n = len(traces)
+    idx = np.zeros((n, slots), np.int64)
+    y = np.zeros((n, slots))
+    mask = np.zeros((n, slots), bool)
+    for i, (sc, tr) in enumerate(zip(scens, traces)):
+        k = len(tr.evaluated)
+        idx[i, :k] = [col[c.key] for c in tr.evaluated]
+        y[i, :k] = [c if r <= sc.limit else c * 5.0
+                    for c, r in zip(tr.costs, tr.runtimes)]
+        mask[i, :k] = True
+
+    def posterior(xo, yo, mo):
+        state = gp_fit(xo, yo, mo, noise=cfg.noise,
+                       median_rows=cfg.max_runs)
+        return gp_joint_posterior(state, x_cand)
+
+    with jax.enable_x64():
+        mean, cov = jax.jit(jax.vmap(posterior))(x_cand[idx], y, mask)
+        mean, cov = np.asarray(mean), np.asarray(cov)
+    var = np.clip(np.diagonal(cov, axis1=1, axis2=2), 1e-9, None)
+    return SupportHistory(
+        workloads=tuple(workloads), seeds=seeds,
+        limits=np.asarray([sc.limit for sc in scens]), traces=traces,
+        grid=SupportGrid(mean=mean, var=np.ascontiguousarray(var),
+                         cov=cov))
+
+
 def _scenario_scores(scenario: Scenario, machine_scores):
     return degrade_scores(machine_scores, scenario.condition)
 
 
 def reference_search(ds: ScoutDataset, scenario: Scenario,
                      machine_scores: Dict[str, Dict[str, float]],
-                     cfg: Optional[ReplayConfig] = None):
+                     cfg: Optional[ReplayConfig] = None,
+                     support: Optional[SupportHistory] = None):
     """The sequential numpy tuner for one scenario — the parity and
-    wall-clock baseline the batched lanes are pinned against."""
+    wall-clock baseline the batched lanes are pinned against. A Karasu
+    scenario takes its support searches from ``support``; the tuner
+    fits its own support models on their observations."""
     from repro.tuning.arrow import Arrow
     from repro.tuning.cherrypick import CherryPick
+    from repro.tuning.karasu import Karasu
     from repro.tuning.perona_weights import PeronaAcquisitionWeighter
 
     cfg = ReplayConfig() if cfg is None else cfg
@@ -274,6 +395,14 @@ def reference_search(ds: ScoutDataset, scenario: Scenario,
             low_fn = (lambda wl, c:
                       machine_score_vector(scores, c.vm_type))
         tuner = Arrow(ds, scenario.limit, low_level_fn=low_fn, **kw)
+    elif scenario.variant.startswith("karasu"):
+        if support is None:
+            raise ValueError("a Karasu scenario needs a support history")
+        past = [(support.traces[row], support.limits[row])
+                for row in support.lane_ids(scenario.workload,
+                                            scenario.seed)]
+        tuner = Karasu(ds, scenario.limit, support=past,
+                       samples=cfg.samples, **kw)
     else:
         tuner = CherryPick(ds, scenario.limit, **kw)
     return tuner.search(scenario.workload)
@@ -281,8 +410,14 @@ def reference_search(ds: ScoutDataset, scenario: Scenario,
 
 def lane_tables(ds: ScoutDataset, scenarios: Sequence[Scenario],
                 machine_scores: Dict[str, Dict[str, float]],
-                cfg: Optional[ReplayConfig] = None) -> LaneTables:
+                cfg: Optional[ReplayConfig] = None,
+                support: Optional[SupportHistory] = None) -> LaneTables:
     """Lower scenarios to the replay engine's stacked lane tables.
+
+    Karasu lanes take CherryPick's features and, from ``support``,
+    their M support rows and the support grid; the other lanes of
+    such a matrix get empty support slots. A matrix without Karasu
+    lanes carries no support tables at all.
 
     Feature layout is unified across variants at D = 6 base + 4
     low-level dims; variants that do not use a block hold it constant,
@@ -375,7 +510,31 @@ def lane_tables(ds: ScoutDataset, scenarios: Sequence[Scenario],
                 init_cache[sc.seed] = np.random.default_rng(sc.seed).choice(
                     n_cand, cfg.n_init, replace=False).astype(np.int32)
             tab.init_idx[lane] = init_cache[sc.seed]
+        if any(sc.variant.startswith("karasu") for sc in scenarios):
+            _lower_support(tab, scenarios, support)
         return tab
+
+
+def _lower_support(tab: LaneTables, scenarios: Sequence[Scenario],
+                   support: Optional[SupportHistory]) -> None:
+    """Each Karasu lane's support rows and search seed (the rows drawn
+    once per distinct seed)."""
+    if support is None:
+        raise ValueError("Karasu lanes need a support history "
+                         "(scenarios.support_history)")
+    ids = np.full((len(scenarios), support.n_support), -1, np.int32)
+    row = {name: w for w, name in enumerate(support.workloads)}
+    by_seed: Dict[int, np.ndarray] = {}
+    for lane, sc in enumerate(scenarios):
+        if sc.variant.startswith("karasu"):
+            if sc.workload not in row:
+                raise ValueError(f"{sc.workload!r} has no support history")
+            if sc.seed not in by_seed:
+                by_seed[sc.seed] = support.seed_ids(sc.seed)
+            ids[lane] = by_seed[sc.seed][row[sc.workload]]
+    tab.support_ids = ids
+    tab.search_seed = np.asarray([sc.seed for sc in scenarios], np.uint32)
+    tab.support_grid = support.grid
 
 
 def lane_spec(ds: ScoutDataset, scenarios: Sequence[Scenario],
@@ -412,6 +571,10 @@ def lane_spec(ds: ScoutDataset, scenarios: Sequence[Scenario],
         init_idx = np.zeros((n_lanes, cfg.n_init), np.int32)
         init_cache: Dict[int, np.ndarray] = {}
         for lane, sc in enumerate(scenarios):
+            if sc.variant.startswith("karasu"):
+                raise ValueError(
+                    "Karasu lanes run on host tables only (seeded=False): "
+                    "the seeded program has no support models")
             row = cond_rows.get(id(sc.condition))
             if row is None:
                 scores = degrade_scores(machine_scores, sc.condition)
@@ -448,14 +611,17 @@ def replay_scenarios(ds: ScoutDataset, scenarios: Sequence[Scenario],
                      cfg: Optional[ReplayConfig] = None,
                      return_result: bool = False, *,
                      devices: Optional[Sequence] = None,
-                     seeded: bool = False):
+                     seeded: bool = False,
+                     support: Optional[SupportHistory] = None):
     """End to end: lower the matrix, run the batched replay (sharded
     over ``devices`` when given), return the per-scenario
     :class:`SearchTrace` list (order matches input).
 
     ``seeded=True`` lowers to the compact :class:`SeededLaneSpec` and
     generates the lane tables inside the compiled program instead of
-    materializing them on host — bit-identical traces."""
+    materializing them on host — bit-identical traces. Karasu
+    scenarios need ``support`` (:func:`support_history`) and the host
+    tables."""
     cfg = ReplayConfig() if cfg is None else cfg
     if seeded:
         spec = lane_spec(ds, scenarios, machine_scores, cfg)
@@ -463,7 +629,7 @@ def replay_scenarios(ds: ScoutDataset, scenarios: Sequence[Scenario],
                                      devices=devices).result()
         traces = traces_from_spec(spec, result, ds.configs)
     else:
-        tab = lane_tables(ds, scenarios, machine_scores, cfg)
+        tab = lane_tables(ds, scenarios, machine_scores, cfg, support)
         result = replay(tab, cfg, devices=devices)
         traces = traces_from_result(tab, result, ds.configs)
     if return_result:

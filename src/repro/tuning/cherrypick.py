@@ -38,6 +38,7 @@ class CherryPick:
         self.max_runs = max_runs
         self.n_init = n_init
         self.ei_threshold = ei_threshold
+        self.seed = seed
         self.rng = np.random.default_rng(seed)
         self.weighter = acquisition_weighter
 
@@ -46,6 +47,11 @@ class CherryPick:
 
     def _on_evaluate(self, workload: str, config: CloudConfig):
         """Hook for subclasses (Arrow records low-level metrics here)."""
+
+    def _predict(self, gp: GP, X: np.ndarray, evaluated, y: np.ndarray):
+        """Posterior (mu, sigma) over the candidates ``X`` that EI reads;
+        a hook for subclasses (Karasu mixes in support models here)."""
+        return gp.predict(X)
 
     def search(self, workload: str) -> SearchTrace:
         configs = list(self.ds.configs)
@@ -75,7 +81,7 @@ class CherryPick:
                 for c, r in zip(costs, runtimes)])
             gp = GP().fit(np.stack([self._features(c) for c in evaluated]),
                           y)
-            mu, sigma = gp.predict(X)
+            mu, sigma = self._predict(gp, X, evaluated, y)
             best = float(np.min(y))
             ei = expected_improvement(mu, sigma, best)
             if self.weighter is not None:

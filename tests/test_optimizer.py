@@ -553,3 +553,252 @@ def test_traces_from_result_fields(ds, machine_scores):
         # no config evaluated twice
         keys = [c.key for c in tr.evaluated]
         assert len(keys) == len(set(keys))
+
+
+# ------------------------------------------- Karasu lanes (RGPE support)
+
+KARASU_VARIANTS = ("cherrypick", "cherrypick+perona", "karasu",
+                   "karasu+perona")
+
+
+@pytest.fixture(scope="module")
+def karasu(ds, machine_scores):
+    """3 workloads (2 support models a lane) x 2 seeds, 4 past searches
+    a workload, 32 posterior samples; Karasu lanes beside the
+    CherryPick lanes they extend."""
+    from types import SimpleNamespace
+
+    from repro.optimizer import support_history
+
+    cfg = ReplayConfig(samples=32)
+    workloads = list(ds.workloads)[:3]
+    hist = support_history(ds, machine_scores, workloads=workloads,
+                           searches=4, cfg=cfg)
+    scens = build_scenarios(ds, workloads=workloads, seeds=(0, 1),
+                            variants=KARASU_VARIANTS,
+                            conditions=(HEALTHY,))
+    tab = lane_tables(ds, scens, machine_scores, cfg, hist)
+    result = replay(tab, cfg)
+    return SimpleNamespace(cfg=cfg, hist=hist, scens=scens, tab=tab,
+                           result=result, workloads=workloads,
+                           traces=traces_from_result(tab, result,
+                                                     ds.configs))
+
+
+def _lane(scens, variant, workload, seed):
+    return next(i for i, sc in enumerate(scens)
+                if (sc.variant, sc.workload, sc.seed)
+                == (variant, workload, seed))
+
+
+def test_karasu_lanes_match_sequential_reference(ds, machine_scores,
+                                                 karasu):
+    """Every lane, Karasu or not, reproduces ``tuning.karasu`` /
+    ``tuning.cherrypick`` exactly, and the support models steer the
+    Karasu lanes off the CherryPick ones."""
+    k = karasu
+    assert k.tab.n_support == 2
+    for sc, bt in zip(k.scens, k.traces):
+        _assert_trace_equal(reference_search(ds, sc, machine_scores,
+                                             k.cfg, support=k.hist),
+                            bt, sc)
+    moved = [k.traces[_lane(k.scens, "karasu", w, s)].evaluated
+             != k.traces[_lane(k.scens, "cherrypick", w, s)].evaluated
+             for w in k.workloads for s in (0, 1)]
+    assert any(moved)
+
+
+def test_karasu_lanes_match_bench_reference(ds, machine_scores, karasu):
+    """The benchmark's own reference, which builds its own support
+    history from its CherryPick copy, gives the same traces."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import scout_inputs
+    from bench.reference import karasu as kref
+
+    k = karasu
+    data = scout_inputs.search_data(ds, k.workloads, machine_scores,
+                                    (HEALTHY,))
+    search_cfg = dict(max_runs=9, n_init=3, ei_threshold=0.1,
+                      noise=1e-3, xi=0.01, strength=0.3, per_dollar=True,
+                      samples=k.cfg.samples)
+    hist = kref.history(data, 4, ds.seed, 40.0, search_cfg)
+    checked = 0
+    for sc, got, peaks in zip(k.scens, k.traces, k.result.peaks):
+        if not sc.variant.startswith("karasu"):
+            continue
+        w = k.workloads.index(sc.workload)
+        want = kref.search(data, hist, w, sc.seed, sc.variant, "healthy",
+                           sc.limit, search_cfg)
+        assert [c.key for c in got.evaluated] == \
+            [data["keys"][i] for i in want.evaluated]
+        assert got.costs == want.costs
+        assert got.best_valid_cost == want.best_valid_cost
+        assert got.search_cost == want.search_cost
+        # each round's peak EI; NaN once the lane has stopped
+        n = len(want.peaks)
+        np.testing.assert_allclose(peaks[:n], want.peaks, rtol=1e-8,
+                                   atol=1e-12)
+        assert np.isnan(peaks[n:]).all()
+        checked += 1
+    assert checked == 12
+
+
+def test_karasu_lane_with_no_support_is_cherrypick(ds, machine_scores,
+                                                   karasu):
+    """With every support slot empty a Karasu lane is its CherryPick
+    lane bit for bit, on the program and in the sequential tuner."""
+    import dataclasses
+
+    from repro.tuning.cherrypick import CherryPick
+    from repro.tuning.karasu import Karasu
+
+    k = karasu
+    empty = dataclasses.replace(
+        k.tab, support_ids=np.full_like(k.tab.support_ids, -1))
+    res = replay(empty, k.cfg)
+    for w in k.workloads:
+        for s in (0, 1):
+            for plain, rgpe in (("cherrypick", "karasu"),
+                                ("cherrypick+perona", "karasu+perona")):
+                a = _lane(k.scens, plain, w, s)
+                b = _lane(k.scens, rgpe, w, s)
+                np.testing.assert_array_equal(res.chosen[a],
+                                              res.chosen[b])
+                assert res.count[a] == res.count[b]
+                np.testing.assert_array_equal(res.chosen[a],
+                                              k.result.chosen[a])
+    sc = k.scens[_lane(k.scens, "cherrypick", k.workloads[0], 1)]
+    seq = Karasu(ds, sc.limit, support=[None, None], samples=32,
+                 seed=1).search(sc.workload)
+    _assert_trace_equal(CherryPick(ds, sc.limit, seed=1)
+                        .search(sc.workload), seq, sc)
+
+
+def test_karasu_needs_host_tables_and_history(ds, machine_scores, karasu):
+    scens = [sc for sc in karasu.scens if sc.variant == "karasu"]
+    with pytest.raises(ValueError, match="host tables"):
+        replay_scenarios(ds, scens, machine_scores, karasu.cfg,
+                         seeded=True, support=karasu.hist)
+    with pytest.raises(ValueError, match="support history"):
+        replay_scenarios(ds, scens, machine_scores, karasu.cfg)
+
+
+def _weights_program(losses, eligible):
+    import jax.numpy as jnp
+
+    from repro.optimizer.acquire import rgpe_weights
+
+    with jax.enable_x64():
+        return np.asarray(jax.jit(rgpe_weights, static_argnums=2)(
+            jnp.asarray(losses, jnp.int32), jnp.asarray(eligible), 9 * 8))
+
+
+def _weights_tuning(losses, eligible):
+    from repro.tuning.karasu import rgpe_weights
+
+    return rgpe_weights(np.asarray(losses), np.asarray(eligible))
+
+
+def _weights_bench(losses, eligible):
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench.reference import karasu as kref
+
+    return kref.weights(np.asarray(losses), np.asarray(eligible))
+
+
+@pytest.mark.parametrize("weights", [_weights_program, _weights_tuning,
+                                     _weights_bench])
+def test_rgpe_weights(weights):
+    """Weights sum to 1 and are shares of samples; a tie splits a
+    sample equally; an empty slot gets nothing; the dilution guard
+    drops a support model that disagrees on most samples (median loss
+    above the target's 95th percentile) although it would win the
+    rest. The program and both references give the same bits."""
+    rng = np.random.default_rng(0)
+    losses = rng.integers(0, 30, size=(5, 64))
+    ok = np.array([True, True, True, False, True])
+    w = weights(losses, ok)
+    assert w.sum() == pytest.approx(1.0, abs=1e-12) and w[3] == 0.0
+    assert np.all(w >= 0)
+    np.testing.assert_array_equal(w, _weights_tuning(losses, ok))
+
+    # sample 0: three-way tie (row 3's median 9 is above the target's
+    # 95th percentile 3.85: dropped); sample 1: rows 0 and 2 tie
+    tie = np.array([[4, 1], [4, 2], [4, 1], [9, 9]])
+    np.testing.assert_allclose(weights(tie, np.ones(4, bool)),
+                               [(1 / 3 + 1 / 2) / 2, 1 / 6,
+                                (1 / 3 + 1 / 2) / 2, 0.0], rtol=1e-15)
+
+    target = np.full(20, 10)  # its 95th percentile: 10
+    rogue = np.where(np.arange(20) < 8, 0, 50)  # median 50, wins 8 of 20
+    both = np.stack([target, rogue])
+    np.testing.assert_array_equal(weights(both, np.ones(2, bool)),
+                                  [1.0, 0.0])
+    honest = np.where(np.arange(20) < 8, 0, 10)  # median 10: kept
+    np.testing.assert_allclose(
+        weights(np.stack([target, honest]), np.ones(2, bool)),
+        [6 / 20, 14 / 20], rtol=1e-15)
+
+
+def test_ranking_losses_count_misordered_pairs():
+    import jax.numpy as jnp
+
+    from repro.optimizer.acquire import ranking_losses
+    from repro.tuning.karasu import ranking_losses as ref_losses
+
+    y = np.array([3.0, 1.0, 2.0, 0.0])
+    mask = np.array([True, True, True, False])
+    f = np.stack([y[None, :3], -y[None, :3]])  # agrees, reversed
+    f = np.concatenate([f, np.zeros((2, 1, 1))], axis=-1)
+    with jax.enable_x64():
+        got = np.asarray(ranking_losses(jnp.asarray(f), jnp.asarray(y),
+                                        jnp.asarray(mask)))
+    np.testing.assert_array_equal(got, [[0], [6]])
+    np.testing.assert_array_equal(ref_losses(f[..., :3], y[:3]), got)
+
+
+def test_karasu_sharded_bit_identical_subprocess():
+    """8 virtual CPU devices: the Karasu program with its lane axis
+    (support ids and seeds with it) sharded and the support grid
+    replicated gives the single-device picks bit for bit."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import jax
+        import numpy as np
+        from repro.optimizer import (HEALTHY, ReplayConfig,
+                                     build_scenarios, lane_tables, replay,
+                                     support_history)
+        from repro.tuning.scout import ScoutDataset, VM_TYPES
+
+        assert jax.device_count() == 8
+        rng = np.random.default_rng(3)
+        scores = {vm: {a: float(rng.uniform(0.5, 2.0))
+                       for a in ("cpu", "memory", "disk", "network")}
+                  for vm in VM_TYPES}
+        ds = ScoutDataset(seed=0)
+        cfg = ReplayConfig(samples=16)
+        wls = list(ds.workloads)[:3]
+        hist = support_history(ds, scores, workloads=wls, searches=2,
+                               cfg=cfg)
+        scens = build_scenarios(ds, workloads=wls, seeds=(0, 1, 2),
+                                variants=("karasu", "karasu+perona"),
+                                conditions=(HEALTHY,))
+        tab = lane_tables(ds, scens, scores, cfg, hist)
+        single = replay(tab, cfg)
+        sharded = replay(tab, cfg, devices=jax.devices())
+        assert np.array_equal(single.chosen, sharded.chosen)
+        assert np.array_equal(single.count, sharded.count)
+        assert np.array_equal(single.peaks, sharded.peaks, equal_nan=True)
+        print("OK karasu bit-identical across", jax.device_count())
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    proc = subprocess.run(
+        [sys.executable, "-c", code],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=env, capture_output=True, text=True, timeout=420)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "OK karasu bit-identical" in proc.stdout

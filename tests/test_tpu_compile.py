@@ -196,5 +196,50 @@ def test_replay_scan_is_loop_free_for_v5e(one_chip, program):
         compiled = lowered.compile()
     hlo = compiled.as_text()
     assert len(re.findall(r"\bwhile\(", hlo)) == 1
+    if program == "tables":
+        # the program of the scout-sec4d cells: Karasu support (M > 0)
+        # adds nothing to it, op for op
+        assert len(re.findall(r"\bfusion\(", hlo)) == 444
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_karasu_replay_scan_is_loop_free_for_v5e(one_chip):
+    """The Karasu program (RGPE over 17 support models, 256 posterior
+    samples, a 144-search support grid) at the 128-lane bucket of the
+    chip smoke test's Karasu matrix, for one chip: the scan is still
+    the only ``while`` (the support models' Cholesky factors are
+    unrolled, the ranking losses and order statistics are reductions)
+    and no float64 contraction reaches the compiler."""
+    from repro.common.rng import x64_streams
+    from repro.optimizer.replay import ReplayConfig, _replay_fn
+
+    cfg = ReplayConfig()
+    lanes, slots, n_cand, dim, support, n_grid = 128, 16, 69, 10, 17, 144
+    rounds = cfg.max_runs - cfg.n_init
+
+    def sds(shape, dtype=jnp.float64):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with x64_streams():
+        fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, None,
+                        support)
+        lowered = fn.lower(
+            (sds((lanes, cfg.max_runs), jnp.int32),
+             sds((lanes,), jnp.int32), sds((lanes,), jnp.bool_)),
+            (sds((lanes, n_cand, dim)), sds((lanes, n_cand, dim)),
+             sds((lanes, n_cand)), sds((lanes, n_cand)),
+             sds((lanes, n_cand, 4)), sds((lanes, n_cand, 4)),
+             sds((lanes, n_cand)), sds((lanes,)),
+             sds((lanes,), jnp.bool_)),
+            (sds((lanes, support), jnp.int32), sds((lanes,), jnp.uint32)),
+            (sds((n_grid, n_cand)), sds((n_grid, n_cand)),
+             sds((n_grid, n_cand, n_cand))))
+        assert not [line for line in lowered.as_text().splitlines()
+                    if re.search(r"dot_general|convolution|"
+                                 r"triangular_solve", line)
+                    and "f64" in line]
+        compiled = lowered.compile()
+    assert len(re.findall(r"\bwhile\(", compiled.as_text())) == 1
     mem = compiled.memory_analysis()
     assert 0 < mem.temp_size_in_bytes < V5E_HBM_BYTES
