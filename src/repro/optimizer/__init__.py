@@ -15,9 +15,11 @@ configuration searches as parallel vmapped lanes on device:
   bit-identical to the single-device scan;
 - :mod:`repro.optimizer.scenarios` — the §IV-D scenario matrix
   (workload x seed x tuner variant x fleet condition) over the scout
-  simulator, including degraded-node fleets from ``fleet.drift``, plus
-  ``replay_pipelined``: fixed-size lane blocks whose host-side table
-  construction overlaps the previous block's device scan. The seeded
+  simulator, including degraded-node fleets from ``fleet.drift``;
+  ``replay_scenarios`` runs a wide matrix as lane blocks, two in
+  flight, so that lowering and trace materialization overlap a block's
+  device scan, and ``replay_pipelined`` does the same with worker
+  threads, which XLA's synchronous CPU backend needs. The seeded
   path (``lane_spec`` / ``replay_seeded``) ships only the compact
   deterministic grid + per-lane ids and re-derives every stochastic
   table cell inside the compiled program from counter-based

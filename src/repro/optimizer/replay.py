@@ -23,11 +23,12 @@ in tests/test_optimizer.py).
 
 ``replay_async`` dispatches and defers the host fetch
 (:class:`PendingReplay`) — a real overlap window on asynchronous
-backends (GPU/TPU dispatch returns before compute finishes). XLA:CPU
-executes synchronously, so there ``scenarios.replay_pipelined``
-produces the overlap instead: per-device worker threads run this same
-entry point while the main thread builds the next lane block's
-tables.
+backends (GPU/TPU dispatch returns before compute finishes):
+``scenarios.replay_scenarios`` dispatches a wide matrix as lane blocks
+through it, lowering the next block and materializing the last one
+while a block scans. XLA:CPU executes synchronously, so there only
+``scenarios.replay_pipelined``'s worker threads overlap host and
+device work.
 
 All math runs in float64 (``jax.enable_x64`` around the
 dispatch) so batched lanes reproduce the sequential scipy traces
@@ -131,7 +132,7 @@ class LaneTables:
 class BatchReplayResult:
     chosen: np.ndarray  # (L, max_runs) evaluated config indices, -1 pad
     count: np.ndarray  # (L,) evaluations performed per lane
-    dispatches: int  # device dispatches of this replay (always 1)
+    dispatches: int  # device dispatches of this replay (its blocks)
     #: (L, rounds) each round's peak EI (see _lane_step); Karasu
     #: programs only
     peaks: Optional[np.ndarray] = None
@@ -489,10 +490,12 @@ def _staging(n_lanes: int, padded: int):
         yield put
 
 
-def _stage_support(put, to_dev, to_all, tables: LaneTables, pad):
+def _stage_support(put, to_dev, to_all, tables: LaneTables, pad,
+                   placed=None):
     """The ``replay.support_tables`` span (inside ``replay.stage``):
     pad and cast the lanes' support ids and search seeds, and place
-    them with the replicated :class:`SupportGrid`; its ``bytes`` arg
+    them with the replicated :class:`SupportGrid`, unless ``placed``
+    holds the grid as an earlier block placed it; its ``bytes`` arg
     counts what was placed, ``models`` the support slots per lane."""
     grid = tables.support_grid
     if grid is None:
@@ -508,9 +511,9 @@ def _stage_support(put, to_dev, to_all, tables: LaneTables, pad):
         lane = tuple(put_counted(to_dev, pad(a)) for a in (
             tables.support_ids.astype(np.int32, copy=False),
             tables.search_seed.astype(np.uint32, copy=False)))
-        shared = tuple(put_counted(to_all, a.astype(np.float64,
-                                                    copy=False))
-                       for a in (grid.mean, grid.var, grid.cov))
+        shared = placed if placed is not None else tuple(
+            put_counted(to_all, a.astype(np.float64, copy=False))
+            for a in (grid.mean, grid.var, grid.cov))
     return lane, shared
 
 
@@ -536,6 +539,9 @@ class PendingReplay:
     _sel: object
     _count: object
     _peaks: object = None
+    #: the placed support grid (Karasu lanes), for a later block of the
+    #: same matrix to reuse (``replay_async(support_grid=...)``)
+    support_grid: Optional[tuple] = None
 
     def result(self) -> BatchReplayResult:
         with obs_trace.span("replay.wait", cat=obs_trace.CAT_DEVICE,
@@ -552,7 +558,9 @@ def replay_async(tables: LaneTables,
                  cfg: Optional[ReplayConfig] = None, *,
                  devices: Optional[Sequence] = None,
                  device=None,
-                 lanes_floor: int = 1) -> PendingReplay:
+                 lanes_floor: int = 1,
+                 support_grid: Optional[tuple] = None,
+                 block: int = 0, blocks: int = 1) -> PendingReplay:
     """Dispatch every lane's full search as one (optionally sharded)
     scanned device call and return without blocking on the outputs.
 
@@ -564,7 +572,11 @@ def replay_async(tables: LaneTables,
     per-device dispatches. ``lanes_floor``: minimum padded lane-bucket
     size (a power of two) — fixed-size lane blocks let differing
     matrix sizes reuse one compiled program (see
-    ``scenarios.replay_pipelined``).
+    ``scenarios.replay_pipelined``). ``support_grid``: the Karasu
+    support grid as an earlier block's :class:`PendingReplay` placed
+    it, reused instead of placed again. ``block`` / ``blocks``: this
+    dispatch's place among a matrix's lane blocks, for the
+    ``replay.dispatch`` span.
     """
     import jax
 
@@ -618,12 +630,14 @@ def replay_async(tables: LaneTables,
             carry0 = _initial_carry(put, to_dev, pad(tables.init_idx),
                                     cfg, lanes)
             if n_support:
-                extra = _stage_support(put, to_dev, to_all, tables, pad)
+                extra = _stage_support(put, to_dev, to_all, tables, pad,
+                                       support_grid)
         # keyed on placement too: each device's first call compiles
         # its own executable and must take the serialized branch
         sig = (cfg, lanes, slots, n_cand, dim, rounds, devs, device,
                n_support)
-        args = {"lanes": n_lanes, "padded": lanes, "rounds": rounds}
+        args = {"lanes": n_lanes, "padded": lanes, "rounds": rounds,
+                "block": block, "blocks": blocks}
         if n_support:
             args.update(support_models=n_support,
                         posterior_samples=cfg.samples)
@@ -634,7 +648,8 @@ def replay_async(tables: LaneTables,
                 with _COMPILE_LOCK:
                     outs = fn(carry0, jnp_tables, *extra)
                     _COMPILED_SIGNATURES.add(sig)
-    return PendingReplay(n_lanes, 1, *outs)
+    return PendingReplay(n_lanes, 1, *outs,
+                         support_grid=extra[1] if extra else None)
 
 
 def replay(tables: LaneTables,
@@ -651,7 +666,9 @@ def replay_seeded_async(spec: SeededLaneSpec,
                         cfg: Optional[ReplayConfig] = None, *,
                         devices: Optional[Sequence] = None,
                         device=None,
-                        lanes_floor: int = 1) -> PendingReplay:
+                        lanes_floor: int = 1,
+                        block: int = 0,
+                        blocks: int = 1) -> PendingReplay:
     """Dispatch a seeded replay: lane tables are generated *inside*
     the compiled program from ``spec``'s grid + per-lane ids, so the
     host ships O(W*C + K*C + L) arrays instead of the O(L*C*D)
@@ -724,7 +741,8 @@ def replay_seeded_async(spec: SeededLaneSpec,
         with REPLAY_TRACES.dispatch(
                 "replay.dispatch_seeded",
                 args={"lanes": n_lanes, "padded": lanes,
-                      "rounds": rounds}):
+                      "rounds": rounds, "block": block,
+                      "blocks": blocks}):
             if sig in _COMPILED_SIGNATURES:
                 sel, count = fn(carry0, lane_args, grid_args)
             else:

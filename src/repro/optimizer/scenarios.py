@@ -28,13 +28,15 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.common.bucketing import next_pow2
+from repro.common.mesh import shard_size
 from repro.common.rng import STREAM_SUPPORT, folded_generator
 from repro.core.ranking import machine_score_matrix, \
     machine_score_vector
 from repro.obs import trace as obs_trace
-from repro.optimizer.replay import (LaneTables, ReplayConfig,
-                                    SeededLaneSpec, SupportGrid, replay,
-                                    replay_async, replay_seeded_async,
+from repro.optimizer.replay import (BatchReplayResult, LaneTables,
+                                    ReplayConfig, SeededLaneSpec,
+                                    SupportGrid, replay, replay_async,
+                                    replay_seeded_async,
                                     traces_from_result,
                                     traces_from_spec)
 from repro.tuning.scout import LOW_CAPS, PRICES, ScoutDataset
@@ -46,6 +48,12 @@ VARIANTS = ("cherrypick", "cherrypick+perona", "arrow", "arrow+perona",
             "karasu", "karasu+perona")
 #: The variants that borrow nothing from other tenants' searches.
 SOLO_VARIANTS = VARIANTS[:4]
+
+#: Lanes per block of a wide matrix in ``replay_scenarios``: a matrix
+#: whose padded lane count reaches two blocks is dispatched as blocks
+#: of this many lanes, so the host lowers the next block and
+#: materializes the last one while a block scans.
+REPLAY_BLOCK_LANES = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -617,13 +625,47 @@ def replay_scenarios(ds: ScoutDataset, scenarios: Sequence[Scenario],
     over ``devices`` when given), return the per-scenario
     :class:`SearchTrace` list (order matches input).
 
+    A matrix whose padded lane count (``shard_size``) reaches twice
+    :data:`REPLAY_BLOCK_LANES` runs as contiguous blocks of that many
+    lanes (:func:`_replay_blocks`), two in flight, with the same
+    traces; one with ``devices=`` keeps its single sharded dispatch.
+
     ``seeded=True`` lowers to the compact :class:`SeededLaneSpec` and
     generates the lane tables inside the compiled program instead of
     materializing them on host — bit-identical traces. Karasu
     scenarios need ``support`` (:func:`support_history`) and the host
     tables."""
     cfg = ReplayConfig() if cfg is None else cfg
-    if seeded:
+    if (devices is None
+            and shard_size(len(scenarios)) >= 2 * REPLAY_BLOCK_LANES):
+        if seeded:
+            def lower(chunk):
+                return lane_spec(ds, chunk, machine_scores, cfg)
+
+            def dispatch(spec, _, **kw):
+                return replay_seeded_async(spec, cfg, **kw)
+        else:
+            karasu = any(sc.variant.startswith("karasu")
+                         for sc in scenarios)
+
+            def lower(chunk):
+                tab = lane_tables(ds, chunk, machine_scores, cfg,
+                                  support)
+                if karasu and tab.support_grid is None:
+                    # a block without Karasu lanes in a matrix with
+                    # them: empty support slots, the matrix's program
+                    _lower_support(tab, chunk, support)
+                return tab
+
+            def dispatch(tab, prev, **kw):
+                # the support grid is placed by the first block only
+                return replay_async(
+                    tab, cfg, support_grid=prev and prev.support_grid,
+                    **kw)
+        traces, result = _replay_blocks(
+            ds, scenarios, lower, dispatch,
+            traces_from_spec if seeded else traces_from_result)
+    elif seeded:
         spec = lane_spec(ds, scenarios, machine_scores, cfg)
         result = replay_seeded_async(spec, cfg,
                                      devices=devices).result()
@@ -635,6 +677,47 @@ def replay_scenarios(ds: ScoutDataset, scenarios: Sequence[Scenario],
     if return_result:
         return traces, result
     return traces
+
+
+def _replay_blocks(ds: ScoutDataset, scenarios: Sequence[Scenario],
+                   lower, dispatch, materialize):
+    """Replay a matrix as contiguous blocks of
+    :data:`REPLAY_BLOCK_LANES` lanes, one compiled program for all of
+    them (only the last block is padded), on one thread with two blocks
+    in flight: block i is lowered and dispatched before block i-1 is
+    fetched and materialized, so that host work overlaps block i's scan
+    on a device whose dispatch returns at once.
+
+    ``lower(chunk)`` gives a block's tables, ``dispatch(tables,
+    pending, **kw)`` launches it (``pending``: the block in flight, or
+    ``None``) and ``materialize(tables, result, configs)`` gives its
+    traces. Returns the traces in lane order and one
+    :class:`BatchReplayResult` of the blocks' outputs, concatenated."""
+    size = REPLAY_BLOCK_LANES
+    blocks = -(-len(scenarios) // size)
+    traces: List = []
+    results: List[BatchReplayResult] = []
+
+    def fetch(tab, pending):
+        result = pending.result()
+        results.append(result)
+        traces.extend(materialize(tab, result, ds.configs))
+
+    in_flight = None  # (tables, PendingReplay) of the block scanning
+    for i in range(blocks):
+        tab = lower(scenarios[i * size:(i + 1) * size])
+        pending = dispatch(tab, in_flight and in_flight[1],
+                           lanes_floor=size, block=i, blocks=blocks)
+        if in_flight is not None:
+            fetch(*in_flight)
+        in_flight = tab, pending
+    fetch(*in_flight)
+    peaks = [r.peaks for r in results]
+    return traces, BatchReplayResult(
+        chosen=np.concatenate([r.chosen for r in results]),
+        count=np.concatenate([r.count for r in results]),
+        dispatches=sum(r.dispatches for r in results),
+        peaks=None if peaks[0] is None else np.concatenate(peaks))
 
 
 def replay_pipelined(ds: ScoutDataset, scenarios: Sequence[Scenario],

@@ -684,6 +684,107 @@ def test_karasu_needs_host_tables_and_history(ds, machine_scores, karasu):
         replay_scenarios(ds, scens, machine_scores, karasu.cfg)
 
 
+# ------------------------------------------------ blocked wide matrices
+
+def _block_matrix(ds, karasu, kind, n_blocks):
+    """A matrix of ``n_blocks`` blocks of 8 lanes, the last one short
+    of a lane (so padded): CherryPick/Arrow lanes on the host or the
+    seeded lowering, or Karasu lanes beside their CherryPick ones,
+    interleaved or after them (so that the first blocks hold none)."""
+    if kind.startswith("karasu"):
+        scens = build_scenarios(ds, workloads=karasu.workloads[:2],
+                                seeds=tuple(range(n_blocks)),
+                                variants=KARASU_VARIANTS,
+                                conditions=(HEALTHY,))
+        if kind == "karasu_last":
+            scens.sort(key=lambda sc: sc.variant.startswith("karasu"))
+        kw = dict(cfg=karasu.cfg, support=karasu.hist)
+    else:
+        scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
+                                seeds=tuple(range(n_blocks)),
+                                conditions=(HEALTHY,))
+        kw = dict(seeded=kind == "seeded")
+    assert len(scens) == 8 * n_blocks
+    return scens[:-1], kw
+
+
+def _replay_spans(run):
+    from repro import obs
+
+    tr = obs.tracer()
+    tr.clear()
+    out = run()
+    return out, tr.events()
+
+
+@pytest.mark.parametrize("n_blocks", [2, 4])
+@pytest.mark.parametrize("kind",
+                         ["host", "seeded", "karasu", "karasu_last"])
+def test_blocked_replay_matches_one_dispatch(ds, machine_scores, karasu,
+                                             monkeypatch, kind,
+                                             n_blocks):
+    """A matrix that reaches two blocks runs as blocks of
+    ``REPLAY_BLOCK_LANES`` lanes, one dispatch each, and gives lane for
+    lane the traces, picks, counts and Karasu peaks of the one
+    dispatch, with no more padded lanes."""
+    from repro.optimizer import scenarios
+
+    scens, kw = _block_matrix(ds, karasu, kind, n_blocks)
+    launch = "replay.dispatch_seeded" if kind == "seeded" \
+        else "replay.dispatch"
+    (ref, one), one_evs = _replay_spans(lambda: replay_scenarios(
+        ds, scens, machine_scores, return_result=True, **kw))
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
+    (got, split), split_evs = _replay_spans(lambda: replay_scenarios(
+        ds, scens, machine_scores, return_result=True, **kw))
+
+    assert len(got) == len(ref) == len(scens)
+    for sc, a, b in zip(scens, ref, got):
+        _assert_trace_equal(a, b, sc)
+    np.testing.assert_array_equal(split.chosen, one.chosen)
+    np.testing.assert_array_equal(split.count, one.count)
+    if kind.startswith("karasu"):
+        np.testing.assert_array_equal(split.peaks, one.peaks)
+    else:
+        assert split.peaks is None and one.peaks is None
+    assert one.dispatches == 1 and split.dispatches == n_blocks
+
+    (whole,) = [e.args for e in one_evs if e.name == launch]
+    assert (whole["block"], whole["blocks"]) == (0, 1)
+    blocks = [e.args for e in split_evs if e.name == launch]
+    assert [(a["block"], a["blocks"]) for a in blocks] == \
+        [(i, n_blocks) for i in range(n_blocks)]
+    assert all(a["padded"] == 8 for a in blocks)
+    assert sum(a["padded"] for a in blocks) == whole["padded"]
+    assert sum(a["lanes"] for a in blocks) == whole["lanes"]
+
+
+def test_blocked_karasu_places_support_grid_once(ds, machine_scores,
+                                                 karasu, monkeypatch):
+    """Each block stages its own support ids and seeds; the support
+    grid is placed by the first block and reused by the others, so the
+    blocks' ``replay.support_tables`` bytes sum to the one dispatch's."""
+    from repro.optimizer import scenarios
+
+    scens, kw = _block_matrix(ds, karasu, "karasu", 4)
+    _, one_evs = _replay_spans(lambda: replay_scenarios(
+        ds, scens, machine_scores, **kw))
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
+    _, split_evs = _replay_spans(lambda: replay_scenarios(
+        ds, scens, machine_scores, **kw))
+
+    grid = karasu.hist.grid
+    grid_bytes = grid.mean.nbytes + grid.var.nbytes + grid.cov.nbytes
+    (whole,) = [e.args["bytes"] for e in one_evs
+                if e.name == "replay.support_tables"]
+    staged = [e.args["bytes"] for e in split_evs
+              if e.name == "replay.support_tables"]
+    assert len(staged) == 4
+    assert sum(staged) == whole
+    lane_bytes = staged[1]
+    assert staged == [lane_bytes + grid_bytes] + [lane_bytes] * 3
+
+
 def _weights_program(losses, eligible):
     import jax.numpy as jnp
 

@@ -1,7 +1,8 @@
 """The configuration-search path's spans: one of each per matrix and
 none per lane, whatever the matrix's width, on the host and the seeded
-lowering alike; under the pipelined replay the per-block staging,
-launch and wait nest in the worker's block span."""
+lowering alike; a matrix split into lane blocks launches each block
+before it fetches the one before; under the pipelined replay the
+per-block staging, launch and wait nest in the worker's block span."""
 
 import collections
 
@@ -46,6 +47,45 @@ def test_one_span_of_each_per_matrix(scout, n_seeds, seeded):
     stage = by_name["replay.stage"].args
     assert stage["padded"] == by_name[launch].args["padded"] >= len(scens)
     assert stage["bytes"] > 0
+    # below two blocks a matrix keeps its one dispatch
+    assert (by_name[launch].args["block"],
+            by_name[launch].args["blocks"]) == (0, 1)
+
+
+@pytest.mark.parametrize("seeded", [False, True])
+def test_blocks_launch_ahead_of_the_fetch(scout, monkeypatch, seeded):
+    """Four blocks of 8 lanes on one thread, two in flight: block i+1
+    is lowered and launched before block i is waited for, and block
+    i's traces are materialized before block i+2 is waited for."""
+    from repro.optimizer import scenarios
+
+    ds, scores = scout
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
+    tr = obs.tracer()
+    tr.clear()
+    scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
+                            seeds=(0, 1, 2, 3), conditions=(HEALTHY,))
+    replay_scenarios(ds, scens, scores, seeded=seeded)
+
+    lower, launch = (("replay.lane_spec", "replay.dispatch_seeded")
+                     if seeded else
+                     ("replay.lane_tables", "replay.dispatch"))
+
+    def spans(name):
+        own = sorted((e for e in tr.events() if e.name == name),
+                     key=lambda e: e.ts)
+        assert len(own) == 4 and all(e.args["lanes"] == 8 for e in own)
+        return own
+
+    low, disp = spans(lower), spans(launch)
+    wait, mat = spans("replay.wait"), spans("replay.materialize_traces")
+    assert [(e.args["block"], e.args["blocks"]) for e in disp] == \
+        [(i, 4) for i in range(4)]
+    for i in range(3):
+        assert low[i + 1].ts < disp[i + 1].ts < wait[i].ts
+    for i in range(2):
+        assert mat[i].ts + mat[i].dur <= wait[i + 2].ts
+    assert mat[3].ts >= wait[3].ts + wait[3].dur
 
 
 def test_pipelined_blocks_nest_their_replay_spans(scout):
