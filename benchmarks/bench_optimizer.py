@@ -16,16 +16,18 @@ dataset, so every lane must reproduce its sequential trace exactly
   assert; compile time is reported separately;
 - ``sharded``    — the same dispatch with the lane axis partitioned
   over the 1-D device mesh (``common.mesh``); bit-identical picks;
-- ``pipelined``  — ``optimizer.replay_pipelined`` on the *large*
+- ``pipelined``  — ``optimizer.replay_scenarios`` on the *large*
   fleet-sweep matrix (12 seeds x 4 fleet conditions, the degraded ones
   derived through the real store path and DEFERRED so the drift
-  simulation runs inside the overlap window): fixed-size lane blocks
-  round-robined over the devices, block N+1's tables built on the
-  host while earlier blocks scan on device. Its wall clock *includes*
-  all host work, so the honest baseline is
-  ``large.unpipelined.wall_s`` = the serial ``replay_scenarios`` path
-  on one device. Both are measured rep-interleaved and reported as
-  medians (ambient load hits both paths equally);
+  simulation runs inside the overlap window). A matrix that wide runs
+  as lane blocks of ``scenarios.REPLAY_BLOCK_LANES``, two in flight on
+  one thread: block N+1's tables are built on the host while block N
+  scans. XLA on the CPU executes synchronously, so here the blocks
+  run one after the other. The wall clock *includes* all host work;
+  the baseline ``large.unpipelined.wall_s`` is the same matrix as one
+  dispatch (``replay_scenarios(devices=...)``). Both are measured
+  rep-interleaved and reported as medians (ambient load hits both
+  paths equally);
 - ``seeded``     — the same scanned program fed the compact
   ``SeededLaneSpec`` instead of host-materialized lane tables: every
   stochastic table cell is re-derived *inside* the compiled program
@@ -33,8 +35,8 @@ dataset, so every lane must reproduce its sequential trace exactly
   picks asserted against the host-table replay. ``huge.*`` scales the
   fleet sweep to a 10^4-lane matrix where host table construction
   dominates: ``huge.lane_tables_s`` vs ``huge.spec_s`` is the
-  O(L*C*D) -> O(W*C + L) host-work drop, and the seeded pipelined
-  end-to-end wall clock is compared against the host-table pipeline
+  O(L*C*D) -> O(W*C + L) host-work drop, and the seeded block path's
+  end-to-end wall clock is compared against the host-table block path
   on the identical matrix (rep-interleaved).
 
 Run under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (or
@@ -170,13 +172,13 @@ def _large_matrix(ds, n_seeds: int, workloads=None):
 
 
 def run(rows, n_workloads: int = 18, n_seeds: int = 3,
-        quick: bool = False, block_lanes: int = 128):
+        quick: bool = False):
     import jax
 
     from repro.common.mesh import pow2_devices, shard_size
     from repro.optimizer import (build_scenarios, lane_spec,
                                  lane_tables, reference_search, replay,
-                                 replay_pipelined, replay_scenarios,
+                                 replay_scenarios,
                                  replay_seeded, traces_from_result,
                                  traces_from_spec, REPLAY_TRACES,
                                  ReplayConfig)
@@ -193,7 +195,6 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
                             conditions=_conditions())
     devices = pow2_devices(jax.devices())
     n_dev = len(devices)
-    block = min(block_lanes, shard_size(len(scens), n_dev))
 
     # --- batched replay: compile, then the warm steady state ---------
     t0 = time.perf_counter()
@@ -226,11 +227,7 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
     assert np.array_equal(shard_result.chosen, result.chosen)
     assert np.array_equal(shard_result.count, result.count)
 
-    # --- pipelined parity on the evaluation matrix -------------------
-    pipelined = replay_pipelined(ds, scens, scores, cfg,
-                                 block_lanes=block, devices=devices)
-
-    # --- large fleet-sweep matrix: pipelined vs unpipelined ----------
+    # --- large fleet-sweep matrix: lane blocks vs one dispatch -------
     # (the multi-device acceptance measurement; deferred store-path
     # conditions resolve inside the overlap window, so each rep builds
     # a fresh matrix)
@@ -241,22 +238,16 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
         return _large_matrix(ds, large_seeds, workloads=large_wls)
 
     n_large = len(large())
-    large_block = min(512, shard_size(n_large, n_dev))
-    replay_scenarios(ds, large(), scores, cfg)
-    replay_pipelined(ds, large(), scores, cfg,
-                     block_lanes=large_block, devices=devices)  # warm
-    replay_pipelined(ds, large(), scores, cfg, seeded=True,
-                     block_lanes=large_block, devices=devices)  # warm
+    replay_scenarios(ds, large(), scores, cfg, devices=devices)  # warm
+    replay_scenarios(ds, large(), scores, cfg)  # warm
+    replay_scenarios(ds, large(), scores, cfg, seeded=True)  # warm
     ((t_unpipe, t_pipe, t_pipe_seed),
      (large_ref, large_piped, large_seeded)) = _interleaved_medians(
-        (lambda: replay_scenarios(ds, large(), scores, cfg),
-         lambda: replay_pipelined(ds, large(), scores, cfg,
-                                  block_lanes=large_block,
+        (lambda: replay_scenarios(ds, large(), scores, cfg,
                                   devices=devices),
-         lambda: replay_pipelined(ds, large(), scores, cfg,
-                                  seeded=True,
-                                  block_lanes=large_block,
-                                  devices=devices)),
+         lambda: replay_scenarios(ds, large(), scores, cfg),
+         lambda: replay_scenarios(ds, large(), scores, cfg,
+                                  seeded=True)),
         reps=2 if quick else 5)
 
     # --- huge fleet sweep: the matrix host tables can't keep up with -
@@ -272,27 +263,16 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
         huge_spec = lane_spec(ds, huge_scens, scores, cfg)
         t_huge_spec = time.perf_counter() - t0
         del huge_tab, huge_spec
-        # parity spot-check on the first block before the timed runs
-        spot = huge_scens[:large_block]
-        _assert_parity(replay_scenarios(ds, spot, scores, cfg),
-                       replay_scenarios(ds, spot, scores, cfg,
-                                        seeded=True))
-        # warm both pipelines over the full sweep: the huge matrix's
-        # condition-boundary blocks hit (block, n_conds, device)
-        # signatures the large phase never compiled
-        replay_pipelined(ds, huge_scens, scores, cfg,
-                         block_lanes=large_block, devices=devices)
-        replay_pipelined(ds, huge_scens, scores, cfg, seeded=True,
-                         block_lanes=large_block, devices=devices)
+        # warm both lowerings over the full sweep: the huge matrix's
+        # condition-boundary blocks hit n_conds signatures the large
+        # phase never compiled
+        replay_scenarios(ds, huge_scens, scores, cfg)
+        replay_scenarios(ds, huge_scens, scores, cfg, seeded=True)
         ((t_huge_host, t_huge_seed),
          (huge_host_traces, huge_seeded_traces)) = _interleaved_medians(
-            (lambda: replay_pipelined(ds, huge_scens, scores, cfg,
-                                      block_lanes=large_block,
-                                      devices=devices),
-             lambda: replay_pipelined(ds, huge_scens, scores, cfg,
-                                      seeded=True,
-                                      block_lanes=large_block,
-                                      devices=devices)),
+            (lambda: replay_scenarios(ds, huge_scens, scores, cfg),
+             lambda: replay_scenarios(ds, huge_scens, scores, cfg,
+                                      seeded=True)),
             reps=1)
         _assert_parity(huge_host_traces, huge_seeded_traces)
         huge = {"lanes": len(huge_scens), "lane_tables_s": t_huge_tab,
@@ -319,9 +299,6 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
                           if diverged(st, bt))
     assert seed_mismatches == 0, \
         f"{seed_mismatches}/{len(scens)} seeded lanes diverged"
-    assert not any(diverged(st, pt)
-                   for st, pt in zip(sequential, pipelined)), \
-        "pipelined lanes diverged from sequential"
     assert not any(diverged(rt, pt)
                    for rt, pt in zip(large_ref, large_piped)), \
         "pipelined large-matrix lanes diverged from unpipelined"
@@ -370,7 +347,6 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
                  f"{t_pipe:.3f}"))
     rows.append(("optimizer.large.pipelined.searches_per_s", "",
                  f"{n_large / max(t_pipe, 1e-9):.1f}"))
-    rows.append(("optimizer.large.block_lanes", "", large_block))
     rows.append(("optimizer.large.pipelined.speedup", "",
                  f"{t_unpipe / max(t_pipe, 1e-9):.2f}x"))
     rows.append(("optimizer.large.pipelined.seeded.wall_s", "",
@@ -397,5 +373,5 @@ def run(rows, n_workloads: int = 18, n_seeds: int = 3,
             "max_runs": cfg.max_runs, "device_count": n_dev,
             "cpu_cores": os.cpu_count(),
             "lanes_per_device": shard_size(n, n_dev) // n_dev,
-            "large_lanes": n_large, "large_block_lanes": large_block,
+            "large_lanes": n_large,
             **{f"huge_{k}": v for k, v in huge.items()}}

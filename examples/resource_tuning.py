@@ -2,8 +2,8 @@
 Perona-weighted acquisition — replayed through the batched BO engine.
 
 The scenario matrix (workload x tuner variant x fleet condition) runs
-as parallel vmapped GP lanes — sharded over every available device and
-host-pipelined in fixed-size lane blocks (``repro.optimizer``), with
+as parallel vmapped GP lanes in one dispatch sharded over every
+available device (``repro.optimizer.replay_scenarios``), with
 the lane tables *generated inside the compiled program* from
 counter-based per-lane seeds (``seeded=True``: the host ships the
 compact ``SeededLaneSpec`` instead of materialized tables); every
@@ -24,7 +24,7 @@ import time
 import numpy as np
 
 from repro.optimizer import (HEALTHY, build_scenarios, drifted_condition,
-                             replay_pipelined)
+                             replay_scenarios)
 from repro.optimizer.scenarios import SOLO_VARIANTS as VARIANTS
 from repro.tuning.perona_weights import fingerprint_machine_scores
 from repro.tuning.scout import VM_TYPES, ScoutDataset, WORKLOAD_NAMES
@@ -52,16 +52,13 @@ def main():
     scens = build_scenarios(ds, workloads=workloads, seeds=(1,),
                             conditions=(HEALTHY, degraded))
     t0 = time.perf_counter()
-    traces, stats = replay_pipelined(ds, scens, scores,
-                                     block_lanes=16, seeded=True,
-                                     devices=jax.devices(),
-                                     return_stats=True)
+    traces = replay_scenarios(ds, scens, scores, seeded=True,
+                              devices=jax.devices())
     dt = time.perf_counter() - t0
     print(f"replayed {len(scens)} searches "
           f"({len(workloads)} workloads x {len(VARIANTS)} variants x "
           f"2 fleet conditions) in {dt:.2f}s — "
-          f"{stats['blocks']} pipelined blocks of "
-          f"{stats['block_lanes']} seeded lanes over "
+          f"{len(traces)} seeded lanes over "
           f"{len(jax.devices())} device(s)\n")
 
     by_key = {(s.workload, s.variant, s.condition.name): t

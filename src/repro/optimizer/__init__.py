@@ -16,10 +16,10 @@ configuration searches as parallel vmapped lanes on device:
 - :mod:`repro.optimizer.scenarios` — the §IV-D scenario matrix
   (workload x seed x tuner variant x fleet condition) over the scout
   simulator, including degraded-node fleets from ``fleet.drift``;
-  ``replay_scenarios`` runs a wide matrix as lane blocks, two in
-  flight, so that lowering and trace materialization overlap a block's
-  device scan, and ``replay_pipelined`` does the same with worker
-  threads, which XLA's synchronous CPU backend needs. The seeded
+  ``replay_scenarios`` is the one way to run a matrix: a wide one
+  runs as lane blocks, two in flight on one thread, so that on a
+  device whose dispatch returns at once (a TPU) lowering and trace
+  materialization overlap a block's scan. The seeded
   path (``lane_spec`` / ``replay_seeded``) ships only the compact
   deterministic grid + per-lane ids and re-derives every stochastic
   table cell inside the compiled program from counter-based
@@ -44,7 +44,6 @@ from repro.optimizer.scenarios import (HEALTHY, SOLO_VARIANTS, VARIANTS,
                                        degrade_scores, drifted_condition,
                                        lane_spec, lane_tables,
                                        reference_search,
-                                       replay_pipelined,
                                        replay_scenarios,
                                        resolve_condition,
                                        simulate_degraded_fleet,
@@ -59,6 +58,6 @@ __all__ = [
     "FleetCondition", "Scenario", "SupportHistory",
     "build_scenarios", "condition_from_drift", "degrade_scores",
     "drifted_condition", "lane_spec", "lane_tables",
-    "reference_search", "replay_pipelined", "replay_scenarios",
+    "reference_search", "replay_scenarios",
     "resolve_condition", "simulate_degraded_fleet", "support_history",
 ]

@@ -21,14 +21,14 @@ single-device scan — and therefore to the sequential scipy traces
 (asserted under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
 in tests/test_optimizer.py).
 
-``replay_async`` dispatches and defers the host fetch
-(:class:`PendingReplay`) — a real overlap window on asynchronous
-backends (GPU/TPU dispatch returns before compute finishes):
-``scenarios.replay_scenarios`` dispatches a wide matrix as lane blocks
-through it, lowering the next block and materializing the last one
-while a block scans. XLA:CPU executes synchronously, so there only
-``scenarios.replay_pipelined``'s worker threads overlap host and
-device work.
+``replay_async`` (and ``replay_seeded_async``) dispatches and defers
+the host fetch (:class:`PendingReplay`). On a backend whose dispatch
+returns before the compute finishes, as a TPU's does, that is an
+overlap window: ``scenarios.replay_scenarios`` dispatches a wide
+matrix as lane blocks through it, lowering the next block and
+materializing the last one while a block scans, on one thread. XLA on
+the CPU executes synchronously, so there the blocks run one after the
+other with the same traces.
 
 All math runs in float64 (``jax.enable_x64`` around the
 dispatch) so batched lanes reproduce the sequential scipy traces
@@ -41,7 +41,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -303,13 +302,6 @@ N_GRID_TABLES = 10
 #: Per-lane arrays of a seeded dispatch (ids + limit).
 N_LANE_ARGS = 4
 
-# first call per program signature traces + compiles; concurrent cold
-# calls from the pipelined per-device workers would each do so (jax
-# does not dedupe concurrent first-call tracing) — serialize only the
-# cold call, warm dispatches stay lock-free
-_COMPILED_SIGNATURES: set = set()
-_COMPILE_LOCK = threading.Lock()
-
 
 @functools.lru_cache(maxsize=32)
 def _replay_fn(cfg: ReplayConfig, lanes: int, slots: int, n_cand: int,
@@ -454,18 +446,16 @@ def _seeded_replay_fn(cfg: ReplayConfig, lanes: int, slots: int,
     return jax.jit(run, donate_argnums=(0,))
 
 
-def _placement(device, devs):
+def _placement(devs):
     """Host-to-device placement of a dispatch's inputs: ``(lane_put,
     grid_put)`` for lane-partitioned and replicated arrays. With a
     device mesh each array goes straight to its sharding, so nothing
-    is staged on the first device; otherwise everything goes to
-    ``device`` (or the default device)."""
+    is staged on the first device; otherwise everything goes to the
+    default device."""
     import jax
 
     if devs is None:
-        def put(a):
-            return jax.device_put(a, device)
-        return put, put
+        return jax.device_put, jax.device_put
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -476,21 +466,24 @@ def _placement(device, devs):
 
 
 @contextlib.contextmanager
-def _staging(n_lanes: int, padded: int):
+def _staging(n_lanes: int, padded: int, devs):
     """The ``replay.stage`` span around padding, casting and placing a
-    dispatch's inputs; yields ``put(place, a)``, which places host
-    array ``a`` and adds its bytes to the span's ``bytes`` arg."""
+    dispatch's inputs; yields ``(to_lanes, to_all)``, which place a
+    lane-partitioned or a replicated host array and add its bytes to
+    the span's ``bytes`` arg."""
     args = {"bytes": 0, "lanes": n_lanes, "padded": padded}
 
-    def put(place, a):
-        args["bytes"] += a.nbytes
-        return place(a)
+    def counted(place):
+        def put(a):
+            args["bytes"] += a.nbytes
+            return place(a)
+        return put
 
     with obs_trace.span("replay.stage", args=args):
-        yield put
+        yield tuple(map(counted, _placement(devs)))
 
 
-def _stage_support(put, to_dev, to_all, tables: LaneTables, pad,
+def _stage_support(pad, to_lanes, to_all, tables: LaneTables,
                    placed=None):
     """The ``replay.support_tables`` span (inside ``replay.stage``):
     pad and cast the lanes' support ids and search seeds, and place
@@ -503,30 +496,18 @@ def _stage_support(put, to_dev, to_all, tables: LaneTables, pad,
                          "support ids index")
     args = {"models": tables.n_support, "bytes": 0}
 
-    def put_counted(place, a):
+    def counted(place, a):
         args["bytes"] += a.nbytes
-        return put(place, a)
+        return place(a)
 
     with obs_trace.span("replay.support_tables", args=args):
-        lane = tuple(put_counted(to_dev, pad(a)) for a in (
+        lane = tuple(counted(to_lanes, pad(a)) for a in (
             tables.support_ids.astype(np.int32, copy=False),
             tables.search_seed.astype(np.uint32, copy=False)))
         shared = placed if placed is not None else tuple(
-            put_counted(to_all, a.astype(np.float64, copy=False))
+            counted(to_all, a.astype(np.float64, copy=False))
             for a in (grid.mean, grid.var, grid.cov))
     return lane, shared
-
-
-def _initial_carry(put, to_dev, init_idx, cfg: ReplayConfig,
-                   lanes: int):
-    """Placed scan carry ``(sel, count, active)``: every lane starts
-    with its ``n_init`` seeded draws (``init_idx``, already padded to
-    ``lanes``) evaluated."""
-    sel0 = np.full((lanes, cfg.max_runs), -1, np.int32)
-    sel0[:, : cfg.n_init] = init_idx
-    count0 = np.full(lanes, cfg.n_init, np.int32)
-    active0 = np.ones(lanes, bool)
-    return tuple(put(to_dev, a) for a in (sel0, count0, active0))
 
 
 @dataclasses.dataclass
@@ -554,37 +535,20 @@ class PendingReplay:
                                  dispatches=self.dispatches, peaks=peaks)
 
 
-def replay_async(tables: LaneTables,
-                 cfg: Optional[ReplayConfig] = None, *,
-                 devices: Optional[Sequence] = None,
-                 device=None,
-                 lanes_floor: int = 1,
-                 support_grid: Optional[tuple] = None,
-                 block: int = 0, blocks: int = 1) -> PendingReplay:
-    """Dispatch every lane's full search as one (optionally sharded)
-    scanned device call and return without blocking on the outputs.
+def _launch(source, cfg: ReplayConfig, devices, lanes_floor: int,
+            program, stage, streams, span: str,
+            args: dict) -> PendingReplay:
+    """The launch body both lowerings share: size the lane bucket for
+    ``source`` (:class:`LaneTables` or :class:`SeededLaneSpec`), stage
+    its inputs and initial carry, and dispatch without blocking.
 
-    ``devices``: shard the lane axis over these devices (pow2 prefix;
-    ``None`` keeps the single-device program). ``device``: place the
-    single-device program's inputs on that device instead of the
-    default — ``replay_pipelined`` round-robins lane blocks over the
-    devices this way, so blocks execute concurrently as independent
-    per-device dispatches. ``lanes_floor``: minimum padded lane-bucket
-    size (a power of two) — fixed-size lane blocks let differing
-    matrix sizes reuse one compiled program (see
-    ``scenarios.replay_pipelined``). ``support_grid``: the Karasu
-    support grid as an earlier block's :class:`PendingReplay` placed
-    it, reused instead of placed again. ``block`` / ``blocks``: this
-    dispatch's place among a matrix's lane blocks, for the
-    ``replay.dispatch`` span.
-    """
-    import jax
-
-    cfg = ReplayConfig() if cfg is None else cfg
-    if devices is not None and device is not None:
-        raise ValueError("pass either devices= (shard_map) or "
-                         "device= (placement), not both")
-    n_lanes = len(tables)
+    ``program(lanes, slots, rounds, devs)`` gives the compiled scan;
+    ``stage(pad, to_lanes, to_all)`` places the lowering's own arrays
+    and returns ``(inputs, support_grid)``, the program's arguments
+    after the carry and the placed grid a later block may reuse.
+    ``streams`` is the x64 context of the dispatch, ``span`` and
+    ``args`` name the ``replay.dispatch*`` span and its extra args."""
+    n_lanes = len(source)
     if n_lanes == 0:
         return PendingReplay(
             n_lanes=0, dispatches=0,
@@ -593,63 +557,89 @@ def replay_async(tables: LaneTables,
     devs = tuple(pow2_devices(devices)) if devices is not None else None
     if devs is not None and len(devs) <= 1:
         devs = None  # same un-sharded program: share its cache entry
-    n_dev = len(devs) if devs else 1
-    lanes = shard_size(n_lanes, n_dev, floor=lanes_floor)
+    lanes = shard_size(n_lanes, len(devs) if devs else 1,
+                       floor=lanes_floor)
     slots = shard_size(cfg.max_runs)
-    n_cand, dim = tables.x_train.shape[1:]
     rounds = cfg.max_runs - cfg.n_init
+    fn = program(lanes, slots, rounds, devs)
 
     def pad(a):  # pad the lane axis by repeating lane 0 (masked out)
         return pad_lanes(a, lanes)
 
     from repro.serving.engine import silence_unusable_donation
 
+    with streams(), silence_unusable_donation():
+        with _staging(n_lanes, lanes, devs) as (to_lanes, to_all):
+            inputs, grid = stage(pad, to_lanes, to_all)
+            # every lane starts with its n_init seeded draws evaluated
+            sel0 = np.full((lanes, cfg.max_runs), -1, np.int32)
+            sel0[:, : cfg.n_init] = pad(source.init_idx)
+            carry0 = tuple(to_lanes(a) for a in (
+                sel0, np.full(lanes, cfg.n_init, np.int32),
+                np.ones(lanes, bool)))
+        with REPLAY_TRACES.dispatch(span, args={
+                "lanes": n_lanes, "padded": lanes, "rounds": rounds,
+                **args}):
+            outs = fn(carry0, *inputs)
+    return PendingReplay(n_lanes, 1, *outs, support_grid=grid)
+
+
+def replay_async(tables: LaneTables,
+                 cfg: Optional[ReplayConfig] = None, *,
+                 devices: Optional[Sequence] = None,
+                 lanes_floor: int = 1,
+                 support_grid: Optional[tuple] = None,
+                 block: int = 0, blocks: int = 1) -> PendingReplay:
+    """Dispatch every lane's full search as one (optionally sharded)
+    scanned device call and return without blocking on the outputs.
+
+    ``devices``: shard the lane axis over these devices (pow2 prefix;
+    ``None`` keeps the single-device program). ``lanes_floor``:
+    minimum padded lane-bucket size (a power of two), so that
+    fixed-size lane blocks of differing matrices reuse one compiled
+    program (``scenarios.replay_scenarios``). ``support_grid``: the
+    Karasu support grid as an earlier block's :class:`PendingReplay`
+    placed it, reused instead of placed again. ``block`` / ``blocks``:
+    this dispatch's place among a matrix's lane blocks, for the
+    ``replay.dispatch`` span.
+    """
+    import jax
+
+    cfg = ReplayConfig() if cfg is None else cfg
+    n_cand, dim = tables.x_train.shape[1:]
     n_support = tables.n_support
-    fn = _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, devs,
-                    n_support)
-    to_dev, to_all = _placement(device, devs)
-    extra = ()
+    args = {"block": block, "blocks": blocks}
+    if n_support:
+        args.update(support_models=n_support,
+                    posterior_samples=cfg.samples)
+
+    def program(lanes, slots, rounds, devs):
+        return _replay_fn(cfg, lanes, slots, n_cand, dim, rounds, devs,
+                          n_support)
+
+    def stage(pad, to_lanes, to_all):
+        # copy=False: lane_tables already builds f64 columns, so the
+        # dtype casts are no-ops for the common path
+        lane = tuple(to_lanes(pad(a)) for a in (
+            tables.x_train.astype(np.float64, copy=False),
+            tables.x_cand.astype(np.float64, copy=False),
+            tables.y.astype(np.float64, copy=False),
+            tables.runtime.astype(np.float64, copy=False),
+            tables.util_low.astype(np.float64, copy=False),
+            tables.norm_scores.astype(np.float64, copy=False),
+            tables.price.astype(np.float64, copy=False),
+            tables.limit.astype(np.float64, copy=False),
+            tables.use_weighter.astype(bool, copy=False)))
+        if not n_support:
+            return (lane,), None
+        support, grid = _stage_support(pad, to_lanes, to_all, tables,
+                                       support_grid)
+        return (lane, support, grid), grid
+
     # Karasu lanes draw RGPE samples: threefry's pinned derivation
     streams = x64_streams if n_support else jax.enable_x64
-
-    with streams(), silence_unusable_donation():
-        with _staging(n_lanes, lanes) as put:
-            # copy=False: lane_tables already builds f64 columns, so
-            # the dtype casts are no-ops for the common path
-            jnp_tables = tuple(
-                put(to_dev, pad(a)) for a in (
-                    tables.x_train.astype(np.float64, copy=False),
-                    tables.x_cand.astype(np.float64, copy=False),
-                    tables.y.astype(np.float64, copy=False),
-                    tables.runtime.astype(np.float64, copy=False),
-                    tables.util_low.astype(np.float64, copy=False),
-                    tables.norm_scores.astype(np.float64, copy=False),
-                    tables.price.astype(np.float64, copy=False),
-                    tables.limit.astype(np.float64, copy=False),
-                    tables.use_weighter.astype(bool, copy=False)))
-            carry0 = _initial_carry(put, to_dev, pad(tables.init_idx),
-                                    cfg, lanes)
-            if n_support:
-                extra = _stage_support(put, to_dev, to_all, tables, pad,
-                                       support_grid)
-        # keyed on placement too: each device's first call compiles
-        # its own executable and must take the serialized branch
-        sig = (cfg, lanes, slots, n_cand, dim, rounds, devs, device,
-               n_support)
-        args = {"lanes": n_lanes, "padded": lanes, "rounds": rounds,
-                "block": block, "blocks": blocks}
-        if n_support:
-            args.update(support_models=n_support,
-                        posterior_samples=cfg.samples)
-        with REPLAY_TRACES.dispatch("replay.dispatch", args=args):
-            if sig in _COMPILED_SIGNATURES:
-                outs = fn(carry0, jnp_tables, *extra)
-            else:
-                with _COMPILE_LOCK:
-                    outs = fn(carry0, jnp_tables, *extra)
-                    _COMPILED_SIGNATURES.add(sig)
-    return PendingReplay(n_lanes, 1, *outs,
-                         support_grid=extra[1] if extra else None)
+    return _launch(tables, cfg, devices, lanes_floor, program, stage,
+                   streams, "replay.dispatch", args)
 
 
 def replay(tables: LaneTables,
@@ -665,7 +655,6 @@ def replay(tables: LaneTables,
 def replay_seeded_async(spec: SeededLaneSpec,
                         cfg: Optional[ReplayConfig] = None, *,
                         devices: Optional[Sequence] = None,
-                        device=None,
                         lanes_floor: int = 1,
                         block: int = 0,
                         blocks: int = 1) -> PendingReplay:
@@ -677,80 +666,41 @@ def replay_seeded_async(spec: SeededLaneSpec,
     The condition axis is pow2-padded so matrices with different
     condition counts reuse one compiled program."""
     cfg = ReplayConfig() if cfg is None else cfg
-    if devices is not None and device is not None:
-        raise ValueError("pass either devices= (shard_map) or "
-                         "device= (placement), not both")
-    n_lanes = len(spec)
-    if n_lanes == 0:
-        return PendingReplay(
-            n_lanes=0, dispatches=0,
-            _sel=np.zeros((0, cfg.max_runs), np.int32),
-            _count=np.zeros(0, np.int32))
-    devs = tuple(pow2_devices(devices)) if devices is not None else None
-    if devs is not None and len(devs) <= 1:
-        devs = None  # same un-sharded program: share its cache entry
-    n_dev = len(devs) if devs else 1
-    lanes = shard_size(n_lanes, n_dev, floor=lanes_floor)
-    slots = shard_size(cfg.max_runs)
     n_cand, base_dim = spec.x_base.shape
-    rounds = cfg.max_runs - cfg.n_init
-    n_workloads = spec.base_runtime.shape[0]
-    # pad the condition axis to pow2: fleet sweeps with differing
-    # condition counts then share one compiled program
     n_conds = shard_size(len(spec.norm_scores))
-    ns, fp = spec.norm_scores, spec.fp_low
-    if n_conds > len(ns):
-        extra = n_conds - len(ns)
-        ns = np.concatenate([ns, np.zeros((extra,) + ns.shape[1:])], 0)
-        fp = np.concatenate([fp, np.zeros((extra,) + fp.shape[1:])], 0)
 
-    def pad(a):  # pad the lane axis by repeating lane 0 (masked out)
-        return pad_lanes(a, lanes)
+    def program(lanes, slots, rounds, devs):
+        return _seeded_replay_fn(cfg, lanes, slots, n_cand, base_dim,
+                                 rounds, spec.base_runtime.shape[0],
+                                 n_conds, float(spec.noise_scale), devs)
 
-    from repro.serving.engine import silence_unusable_donation
+    def stage(pad, to_lanes, to_all):
+        # pad the condition axis to pow2: fleet sweeps with differing
+        # condition counts then share one compiled program
+        ns, fp = (np.concatenate(
+            [a, np.zeros((n_conds - len(a),) + a.shape[1:])], 0)
+            for a in (spec.norm_scores, spec.fp_low))
+        lane_args = tuple(to_lanes(pad(a)) for a in (
+            spec.workload_id.astype(np.int32, copy=False),
+            spec.condition_id.astype(np.int32, copy=False),
+            spec.variant_id.astype(np.int32, copy=False),
+            spec.limit.astype(np.float64, copy=False)))
+        grid_args = tuple(to_all(a) for a in (
+            spec.base_runtime.astype(np.float64, copy=False),
+            spec.low_num.astype(np.float64, copy=False),
+            spec.low_caps.astype(np.float64, copy=False),
+            spec.x_base.astype(np.float64, copy=False),
+            spec.price.astype(np.float64, copy=False),
+            spec.count.astype(np.float64, copy=False),
+            spec.config_uid.astype(np.int32, copy=False),
+            ns.astype(np.float64, copy=False),
+            fp.astype(np.float64, copy=False),
+            spec.noise_key))
+        return (lane_args, grid_args), None
 
-    fn = _seeded_replay_fn(cfg, lanes, slots, n_cand, base_dim, rounds,
-                           n_workloads, n_conds,
-                           float(spec.noise_scale), devs)
-    to_dev, to_all = _placement(device, devs)
-
-    with x64_streams(), silence_unusable_donation():
-        with _staging(n_lanes, lanes) as put:
-            lane_args = tuple(
-                put(to_dev, pad(a)) for a in (
-                    spec.workload_id.astype(np.int32, copy=False),
-                    spec.condition_id.astype(np.int32, copy=False),
-                    spec.variant_id.astype(np.int32, copy=False),
-                    spec.limit.astype(np.float64, copy=False)))
-            grid_args = tuple(
-                put(to_all, a) for a in (
-                    spec.base_runtime.astype(np.float64, copy=False),
-                    spec.low_num.astype(np.float64, copy=False),
-                    spec.low_caps.astype(np.float64, copy=False),
-                    spec.x_base.astype(np.float64, copy=False),
-                    spec.price.astype(np.float64, copy=False),
-                    spec.count.astype(np.float64, copy=False),
-                    spec.config_uid.astype(np.int32, copy=False),
-                    ns.astype(np.float64, copy=False),
-                    fp.astype(np.float64, copy=False),
-                    spec.noise_key))
-            carry0 = _initial_carry(put, to_dev, pad(spec.init_idx),
-                                    cfg, lanes)
-        sig = ("seeded", cfg, lanes, slots, n_cand, base_dim, rounds,
-               n_workloads, n_conds, devs, device)
-        with REPLAY_TRACES.dispatch(
-                "replay.dispatch_seeded",
-                args={"lanes": n_lanes, "padded": lanes,
-                      "rounds": rounds, "block": block,
-                      "blocks": blocks}):
-            if sig in _COMPILED_SIGNATURES:
-                sel, count = fn(carry0, lane_args, grid_args)
-            else:
-                with _COMPILE_LOCK:
-                    sel, count = fn(carry0, lane_args, grid_args)
-                    _COMPILED_SIGNATURES.add(sig)
-    return PendingReplay(n_lanes=n_lanes, dispatches=1,
-                         _sel=sel, _count=count)
+    return _launch(spec, cfg, devices, lanes_floor, program, stage,
+                   x64_streams, "replay.dispatch_seeded",
+                   {"block": block, "blocks": blocks})
 
 
 def replay_seeded(spec: SeededLaneSpec,
@@ -770,8 +720,8 @@ def traces_from_result(tables: LaneTables, result: BatchReplayResult,
 
     Vectorized across lanes (one gather + running-min per field): the
     per-lane python work is just the object construction, which keeps
-    trace materialization cheap enough to overlap with device scans in
-    the pipelined path."""
+    trace materialization cheap enough to overlap with a lane block's
+    device scan."""
     n = len(tables)
     if n == 0:
         return []

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -36,7 +35,7 @@ from repro.obs import trace as obs_trace
 from repro.optimizer.replay import (BatchReplayResult, LaneTables,
                                     ReplayConfig, SeededLaneSpec,
                                     SupportGrid, replay, replay_async,
-                                    replay_seeded_async,
+                                    replay_seeded, replay_seeded_async,
                                     traces_from_result,
                                     traces_from_spec)
 from repro.tuning.scout import LOW_CAPS, PRICES, ScoutDataset
@@ -73,10 +72,11 @@ class DeferredFleetCondition:
     """A fleet condition whose score drops are derived on first use —
     typically through the real store path (``simulate_degraded_fleet``
     -> ``fleet.drift`` EWMAs -> ``condition_from_drift``), which costs
-    real host time. ``replay_pipelined`` exploits the laziness: with a
-    condition-major scenario order (``build_scenarios(
-    condition_major=True)``) each block's conditions are derived on the
-    host while the previous block's scan runs on device."""
+    real host time. Under the block path of ``replay_scenarios`` the
+    laziness pays: with a condition-major scenario order
+    (``build_scenarios(condition_major=True)``) each block's conditions
+    are derived on the host while the previous block scans on the
+    device. Resolving is thread-safe."""
 
     def __init__(self, name: str, factory):
         self.name = name
@@ -89,11 +89,10 @@ class DeferredFleetCondition:
         return self._resolved is not None
 
     def resolve(self) -> FleetCondition:
-        # double-checked: concurrent resolvers (pipelined per-device
-        # workers touching a shared condition) must not run the
-        # factory twice — beyond the wasted store-path simulation, two
-        # FleetCondition objects would split the replay engine's
-        # id()-keyed condition caches
+        # double-checked: concurrent resolvers of a shared condition
+        # must not run the factory twice — beyond the wasted
+        # store-path simulation, two FleetCondition objects would
+        # split the replay engine's id()-keyed condition caches
         if self._resolved is None:
             with self._lock:
                 if self._resolved is None:
@@ -201,7 +200,8 @@ def drifted_condition(machine_types: Sequence[str],
 
     ``deferred=True`` returns a :class:`DeferredFleetCondition` that
     runs the store-path simulation on first use instead of now — the
-    pipelined replay then overlaps that host work with device scans."""
+    block path of ``replay_scenarios`` then overlaps that host work
+    with device scans."""
     if name is None:
         name = f"{'/'.join(machine_types)}-{'/'.join(aspects)}-degraded"
 
@@ -238,10 +238,10 @@ def build_scenarios(ds: ScoutDataset, *,
 
     ``condition_major=True`` orders the matrix condition-outermost, so
     every contiguous lane block touches as few conditions as possible
-    — with deferred (store-path-derived) conditions, the pipelined
-    replay then derives each block's conditions while the previous
-    block runs on device. Building the matrix never resolves deferred
-    conditions."""
+    — with deferred (store-path-derived) conditions, the block path
+    of ``replay_scenarios`` then derives each block's conditions while
+    the previous block runs on device. Building the matrix never
+    resolves deferred conditions."""
     workloads = list(ds.workloads) if workloads is None else workloads
     args: Dict[str, object] = {}
     with obs_trace.span("replay.build_scenarios", args=args):
@@ -568,7 +568,7 @@ def lane_spec(ds: ScoutDataset, scenarios: Sequence[Scenario],
 
         # one score-matrix pair per distinct condition object (identity
         # keyed: distinct conditions may share a name); resolving a
-        # deferred condition happens here, on the host, thread-safely
+        # deferred condition happens here, on the host
         cond_rows: Dict[int, int] = {}
         ns_rows: List[np.ndarray] = []
         fp_rows: List[np.ndarray] = []
@@ -607,7 +607,9 @@ def lane_spec(ds: ScoutDataset, scenarios: Sequence[Scenario],
             x_base=grid.x_base, price=grid.price,
             count=grid.count.astype(np.float64, copy=False),
             config_uid=grid.config_uid,
-            norm_scores=np.stack(ns_rows), fp_low=np.stack(fp_rows),
+            # reshaped, not stacked: an empty matrix has no rows
+            norm_scores=np.asarray(ns_rows).reshape(-1, n_cand, 4),
+            fp_low=np.asarray(fp_rows).reshape(-1, n_cand, 4),
             noise_key=grid.noise_key, noise_scale=CONTENTION_SCALE,
             workload_id=workload_id, condition_id=condition_id,
             variant_id=variant_id, limit=limit, init_idx=init_idx,
@@ -636,51 +638,44 @@ def replay_scenarios(ds: ScoutDataset, scenarios: Sequence[Scenario],
     scenarios need ``support`` (:func:`support_history`) and the host
     tables."""
     cfg = ReplayConfig() if cfg is None else cfg
+    if seeded:
+        def lower(chunk):
+            return lane_spec(ds, chunk, machine_scores, cfg)
+
+        def launch(spec, _, **kw):
+            return replay_seeded_async(spec, cfg, **kw)
+        run, materialize = replay_seeded, traces_from_spec
+    else:
+        karasu = any(sc.variant.startswith("karasu") for sc in scenarios)
+
+        def lower(chunk):
+            tab = lane_tables(ds, chunk, machine_scores, cfg, support)
+            if karasu and tab.support_grid is None:
+                # a block without Karasu lanes in a matrix with them:
+                # empty support slots, the matrix's program
+                _lower_support(tab, chunk, support)
+            return tab
+
+        def launch(tab, prev, **kw):
+            # the support grid is placed by the first block only
+            return replay_async(
+                tab, cfg, support_grid=prev and prev.support_grid, **kw)
+        run, materialize = replay, traces_from_result
     if (devices is None
             and shard_size(len(scenarios)) >= 2 * REPLAY_BLOCK_LANES):
-        if seeded:
-            def lower(chunk):
-                return lane_spec(ds, chunk, machine_scores, cfg)
-
-            def dispatch(spec, _, **kw):
-                return replay_seeded_async(spec, cfg, **kw)
-        else:
-            karasu = any(sc.variant.startswith("karasu")
-                         for sc in scenarios)
-
-            def lower(chunk):
-                tab = lane_tables(ds, chunk, machine_scores, cfg,
-                                  support)
-                if karasu and tab.support_grid is None:
-                    # a block without Karasu lanes in a matrix with
-                    # them: empty support slots, the matrix's program
-                    _lower_support(tab, chunk, support)
-                return tab
-
-            def dispatch(tab, prev, **kw):
-                # the support grid is placed by the first block only
-                return replay_async(
-                    tab, cfg, support_grid=prev and prev.support_grid,
-                    **kw)
-        traces, result = _replay_blocks(
-            ds, scenarios, lower, dispatch,
-            traces_from_spec if seeded else traces_from_result)
-    elif seeded:
-        spec = lane_spec(ds, scenarios, machine_scores, cfg)
-        result = replay_seeded_async(spec, cfg,
-                                     devices=devices).result()
-        traces = traces_from_spec(spec, result, ds.configs)
+        traces, result = _replay_blocks(ds, scenarios, lower, launch,
+                                        materialize)
     else:
-        tab = lane_tables(ds, scenarios, machine_scores, cfg, support)
-        result = replay(tab, cfg, devices=devices)
-        traces = traces_from_result(tab, result, ds.configs)
+        lowered = lower(scenarios)
+        result = run(lowered, cfg, devices=devices)
+        traces = materialize(lowered, result, ds.configs)
     if return_result:
         return traces, result
     return traces
 
 
 def _replay_blocks(ds: ScoutDataset, scenarios: Sequence[Scenario],
-                   lower, dispatch, materialize):
+                   lower, launch, materialize):
     """Replay a matrix as contiguous blocks of
     :data:`REPLAY_BLOCK_LANES` lanes, one compiled program for all of
     them (only the last block is padded), on one thread with two blocks
@@ -688,7 +683,7 @@ def _replay_blocks(ds: ScoutDataset, scenarios: Sequence[Scenario],
     fetched and materialized, so that host work overlaps block i's scan
     on a device whose dispatch returns at once.
 
-    ``lower(chunk)`` gives a block's tables, ``dispatch(tables,
+    ``lower(chunk)`` gives a block's tables, ``launch(tables,
     pending, **kw)`` launches it (``pending``: the block in flight, or
     ``None``) and ``materialize(tables, result, configs)`` gives its
     traces. Returns the traces in lane order and one
@@ -706,8 +701,8 @@ def _replay_blocks(ds: ScoutDataset, scenarios: Sequence[Scenario],
     in_flight = None  # (tables, PendingReplay) of the block scanning
     for i in range(blocks):
         tab = lower(scenarios[i * size:(i + 1) * size])
-        pending = dispatch(tab, in_flight and in_flight[1],
-                           lanes_floor=size, block=i, blocks=blocks)
+        pending = launch(tab, in_flight and in_flight[1],
+                         lanes_floor=size, block=i, blocks=blocks)
         if in_flight is not None:
             fetch(*in_flight)
         in_flight = tab, pending
@@ -718,130 +713,3 @@ def _replay_blocks(ds: ScoutDataset, scenarios: Sequence[Scenario],
         count=np.concatenate([r.count for r in results]),
         dispatches=sum(r.dispatches for r in results),
         peaks=None if peaks[0] is None else np.concatenate(peaks))
-
-
-def replay_pipelined(ds: ScoutDataset, scenarios: Sequence[Scenario],
-                     machine_scores: Dict[str, Dict[str, float]],
-                     cfg: Optional[ReplayConfig] = None, *,
-                     block_lanes: int = 128,
-                     devices: Optional[Sequence] = None,
-                     shard_blocks: bool = False,
-                     seeded: bool = False,
-                     return_stats: bool = False):
-    """Host-pipelined replay of a large scenario matrix over per-device
-    lane buckets.
-
-    The matrix is chunked into fixed-size lane blocks; block N+1's
-    tables — workload arrays, deferred (store-path-derived) fleet
-    conditions, condition score matrices, seeded init draws — are
-    built on the host *while earlier blocks run on device*. Blocks are
-    round-robined over ``devices`` as independent single-program
-    dispatches (``replay_async(device=...)``), one worker thread per
-    device, up to ``len(devices)`` dispatches in flight: devices
-    execute different lane buckets concurrently while the main thread
-    keeps building tables and materializing finished blocks' traces (a
-    double-buffered loop generalized to mesh depth; XLA releases the
-    GIL during execution).
-
-    Every block pads its lane axis to the same ``block_lanes`` bucket
-    (lane padding repeats lane 0, masked out), so ONE traced program
-    serves any matrix size — replaying 100-, 200- and 432-lane matrices
-    reuses a single trace (``REPLAY_TRACES``; asserted in
-    tests/test_optimizer.py). Results are identical to the unpipelined
-    ``replay_scenarios`` lane-for-lane: blocks never interact, and a
-    lane's math does not depend on which device runs it.
-
-    ``shard_blocks=True`` instead partitions each block's lane axis
-    over ALL the devices with one ``shard_map`` dispatch in flight
-    (the whole-matrix sharded layout, blocked for table overlap):
-    prefer it when a single block saturates the mesh; the default
-    round-robin keeps devices busy on independent blocks.
-
-    ``seeded=True`` lowers each block to the compact
-    :class:`SeededLaneSpec` (O(block) host work per block instead of
-    O(block x candidates x dims)) and generates the lane tables inside
-    the compiled program — same traces, far less host table time, so
-    the pipeline stays device-bound at matrix sizes where host table
-    construction would otherwise dominate.
-
-    Returns the per-scenario trace list; with ``return_stats`` also a
-    dict of pipeline counters (blocks, dispatches, device count, host
-    table seconds).
-    """
-    from concurrent.futures import ThreadPoolExecutor
-
-    from repro.common.mesh import pow2_devices
-
-    cfg = ReplayConfig() if cfg is None else cfg
-    if shard_blocks and devices is None:
-        raise ValueError("shard_blocks=True needs devices= (the mesh "
-                         "to partition each block over)")
-    block = next_pow2(max(block_lanes, 1))
-    devs = pow2_devices(devices) if devices is not None else [None]
-    devs = devs or [None]  # empty device list -> default placement
-    if shard_blocks:
-        devs = [None]  # one shard_map dispatch in flight at a time
-    traces: List = []
-    stats = {"blocks": 0, "dispatches": 0, "block_lanes": block,
-             "devices": (len(pow2_devices(devices))
-                         if devices is not None else 1),
-             "table_s": 0.0}
-
-    dispatch = replay_seeded_async if seeded else replay_async
-
-    def run_block(tab, dev, block_idx):
-        # worker thread: dispatch + device wait (GIL released inside
-        # XLA); per-device workers keep each device's blocks in order.
-        # The span lands on the worker's own timeline track — its
-        # overlap with the main thread's replay.lane_tables spans IS
-        # the pipelining (asserted in tests/test_obs.py).
-        with obs_trace.span("replay.block_scan",
-                            cat=obs_trace.CAT_DEVICE,
-                            args={"block": block_idx,
-                                  "lanes": len(tab)}):
-            if shard_blocks:
-                return dispatch(tab, cfg, devices=devices,
-                                lanes_floor=block).result()
-            return dispatch(tab, cfg, device=dev,
-                            lanes_floor=block).result()
-
-    def collect(tab, future):
-        result = future.result()
-        stats["dispatches"] += result.dispatches
-        if seeded:
-            traces.extend(traces_from_spec(tab, result, ds.configs))
-        else:
-            traces.extend(traces_from_result(tab, result, ds.configs))
-
-    in_flight: List = []  # (tables, future), submission order
-    # one single-worker pool per device: a device's blocks dispatch in
-    # order from its own thread, and a long-running block on one
-    # device never steals the worker a later block needs for another
-    pools = [ThreadPoolExecutor(max_workers=1) for _ in devs]
-    try:
-        for i, start in enumerate(range(0, len(scenarios), block)):
-            chunk = scenarios[start:start + block]
-            # host work, overlapped with earlier blocks' device scans
-            t0 = time.perf_counter()
-            if seeded:
-                tab = lane_spec(ds, chunk, machine_scores, cfg)
-            else:
-                tab = lane_tables(ds, chunk, machine_scores, cfg)
-            stats["table_s"] += time.perf_counter() - t0
-            d = i % len(devs)
-            in_flight.append(
-                (tab, pools[d].submit(run_block, tab, devs[d], i)))
-            stats["blocks"] += 1
-            # drain finished blocks (in order) without blocking, and
-            # cap the queue at one block per device
-            while in_flight and (in_flight[0][1].done()
-                                 or len(in_flight) > len(devs)):
-                collect(*in_flight.pop(0))
-        for pending in in_flight:
-            collect(*pending)
-    finally:
-        for pool in pools:
-            pool.shutdown(wait=True)
-    if return_stats:
-        return traces, stats
-    return traces

@@ -1,9 +1,9 @@
 """Unified telemetry plane: metrics registry semantics, span tracing
 over injectable clocks, Chrome trace-event export/validation, JitSite
 consolidation of the jit trace counters, disabled-mode no-ops, and the
-two end-to-end timelines the PR promises — a daemon fault storm with
-named ladder transitions, and a pipelined replay whose host table
-builds overlap device block scans on separate thread tracks."""
+two end-to-end timelines — a daemon fault storm with named ladder
+transitions, and a replay of a matrix as lane blocks, one dispatch
+per block in order."""
 
 import contextlib
 import json
@@ -515,13 +515,12 @@ def test_daemon_core_stats_survive_disabled_plane(setup):
     assert daemon._m_events.value == 0  # mirror stayed quiet
 
 
-def test_pipelined_replay_host_device_overlap(tmp_path):
-    """replay_pipelined's host table-build spans (main thread) overlap
-    the device block-scan spans (per-device worker threads) on the
-    process tracer — the pipelining is visible in the exported
-    timeline as intersecting intervals on different thread tracks."""
+def test_pipelined_replay_host_device_overlap(tmp_path, monkeypatch):
+    """A matrix replayed as lane blocks exports to a valid Chrome trace
+    with one ``replay.dispatch`` per block, in block order, each
+    followed by its block's ``replay.wait`` on the device category."""
     from repro.optimizer import (HEALTHY, build_scenarios,
-                                 replay_pipelined)
+                                 replay_scenarios, scenarios)
     from repro.tuning.scout import ScoutDataset, VM_TYPES, \
         WORKLOAD_NAMES
 
@@ -532,27 +531,26 @@ def test_pipelined_replay_host_device_overlap(tmp_path):
               for vm in VM_TYPES}
     scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
                             seeds=(0, 1), conditions=(HEALTHY,))
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 4)
     tr = obs.tracer()
     tr.clear()
-    traces = replay_pipelined(ds, scens, scores, block_lanes=4)
-    assert len(traces) == len(scens)
+    traces = replay_scenarios(ds, scens, scores)
+    assert len(traces) == len(scens) == 16
 
-    evs = tr.events()
-    builds = [e for e in evs if e.name == "replay.lane_tables"]
-    scans = [e for e in evs if e.name == "replay.block_scan"]
-    assert len(builds) == len(scans) == len(scens) // 4
-    assert all(e.cat == CAT_DEVICE for e in scans)
-    # worker-thread device track(s) distinct from the main host track
-    assert {e.tid for e in scans}.isdisjoint({e.tid for e in builds})
-    overlap = any(
-        b.ts < s.ts + s.dur and s.ts < b.ts + b.dur
-        for b in builds for s in scans)
-    assert overlap, "no host build span overlapped a device scan span"
-
-    path = str(tmp_path / "pipe.json")
+    path = str(tmp_path / "blocks.json")
     write_chrome_trace(path, tracer=tr)
     summary = validate_chrome_trace_file(path)
-    assert summary["threads"] >= 2
+    assert summary["threads"] >= 1
+    with open(path) as f:
+        events = sorted((e for e in json.load(f)["traceEvents"]
+                         if e["ph"] == "X"), key=lambda e: e["ts"])
+    launches = [e["args"] for e in events
+                if e["name"] == "replay.dispatch"]
+    assert [(a["block"], a["blocks"]) for a in launches] == \
+        [(i, 4) for i in range(4)]
+    assert all(a["lanes"] == a["padded"] == 4 for a in launches)
+    waits = [e for e in events if e["name"] == "replay.wait"]
+    assert len(waits) == 4 and all(e["cat"] == CAT_DEVICE for e in waits)
 
 
 REPLAY_SPANS = ("replay.build_scenarios", "replay.lane_tables",

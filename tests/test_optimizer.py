@@ -1,7 +1,7 @@
 """Batched BO replay engine: GP pinned against the scipy reference,
 per-seed trace parity with CherryPick/Arrow, Perona-weighting
 equivalence, degraded-fleet scenarios, compile amortization, sharded
-lane-axis bit parity and the host-pipelined block path."""
+lane-axis bit parity and the block path of wide matrices."""
 
 import os
 import subprocess
@@ -17,8 +17,7 @@ from repro.optimizer import (HEALTHY, FleetCondition, ReplayConfig,
                              REPLAY_TRACES, build_scenarios,
                              condition_from_drift, degrade_scores,
                              lane_spec, lane_tables, reference_search,
-                             replay, replay_pipelined,
-                             replay_scenarios, replay_seeded,
+                             replay, replay_scenarios, replay_seeded,
                              simulate_degraded_fleet,
                              traces_from_result, traces_from_spec)
 from repro.tuning.scout import ScoutDataset, VM_TYPES, WORKLOAD_NAMES
@@ -275,23 +274,27 @@ def _assert_same_traces(ref_traces, got_traces):
         assert a.best_valid_cost == b.best_valid_cost
 
 
-def test_pipelined_matches_unpipelined(ds, machine_scores):
-    """Blocked, double-buffered replay is lane-for-lane identical to
-    the one-dispatch path (blocks never interact) — in both dispatch
-    modes (round-robin per-device placement and sharded blocks)."""
+def test_pipelined_matches_unpipelined(ds, machine_scores, monkeypatch):
+    """A matrix wide enough for two blocks runs as lane blocks, two in
+    flight; with ``devices=`` it keeps its one (sharded) dispatch. Both
+    give the same traces, picks and counts lane for lane: blocks never
+    interact."""
+    from repro.optimizer import scenarios
+
     scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
                             seeds=(0, 1), conditions=(HEALTHY,))
-    ref = replay_scenarios(ds, scens, machine_scores)
-    got, stats = replay_pipelined(ds, scens, machine_scores,
-                                  block_lanes=8, return_stats=True)
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
+    (ref, whole), whole_evs = _replay_spans(lambda: replay_scenarios(
+        ds, scens, machine_scores, return_result=True,
+        devices=jax.devices()))
+    got, split = replay_scenarios(ds, scens, machine_scores,
+                                  return_result=True)
     _assert_same_traces(ref, got)
-    assert stats["block_lanes"] == 8
-    assert stats["blocks"] == stats["dispatches"] == 2
-    assert stats["table_s"] > 0.0
-    sharded = replay_pipelined(ds, scens, machine_scores,
-                               block_lanes=8, devices=jax.devices(),
-                               shard_blocks=True)
-    _assert_same_traces(ref, sharded)
+    np.testing.assert_array_equal(whole.chosen, split.chosen)
+    np.testing.assert_array_equal(whole.count, split.count)
+    assert whole.dispatches == 1 and split.dispatches == 2
+    (launch,) = [e.args for e in whole_evs if e.name == "replay.dispatch"]
+    assert (launch["block"], launch["blocks"]) == (0, 1)
 
 
 def test_deferred_condition_resolves_lazily(ds, machine_scores):
@@ -345,23 +348,44 @@ def test_condition_major_order_same_traces(ds, machine_scores):
         assert ta[k].best_valid_cost == tb[k].best_valid_cost
 
 
-def test_pipelined_empty_and_partial_block(ds, machine_scores):
-    assert replay_pipelined(ds, [], machine_scores) == []
+@pytest.mark.parametrize("seeded", [False, True],
+                         ids=["host", "seeded"])
+def test_pipelined_empty_and_partial_block(ds, machine_scores,
+                                           monkeypatch, seeded):
+    """Both lowerings: an empty matrix gives no traces and no dispatch,
+    a one-lane matrix its reference trace, and a matrix one lane past a
+    block runs as a full block and a one-lane block with the whole
+    matrix's traces."""
+    from repro.optimizer import scenarios
+
+    traces, result = replay_scenarios(ds, [], machine_scores,
+                                      return_result=True, seeded=seeded)
+    assert traces == [] and result.dispatches == 0
     scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:1],
-                            seeds=(0,), variants=("cherrypick",),
-                            conditions=(HEALTHY,))
-    ref = replay_scenarios(ds, scens, machine_scores)
-    got = replay_pipelined(ds, scens, machine_scores, block_lanes=8)
+                            seeds=(0, 1), conditions=(HEALTHY,))
+    (got,) = replay_scenarios(ds, scens[:1], machine_scores,
+                              seeded=seeded)
+    _assert_trace_equal(reference_search(ds, scens[0], machine_scores),
+                        got, scens[0])
+    scens = scens + scens[:1]
+    ref = replay_scenarios(ds, scens, machine_scores, seeded=seeded)
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
+    got, split = replay_scenarios(ds, scens, machine_scores,
+                                  return_result=True, seeded=seeded)
+    assert len(scens) == 9 and split.dispatches == 2
     _assert_same_traces(ref, got)
 
 
 @pytest.mark.slow
 def test_trace_amortized_across_lane_counts(ds, machine_scores,
-                                            degraded_condition):
-    """100-, 200- and 432-lane matrices: the unpipelined path compiles
+                                            degraded_condition,
+                                            monkeypatch):
+    """100-, 200- and 432-lane matrices: the one-dispatch path compiles
     one program per pow2 lane bucket (128/256/512) and reuses it, the
-    pipelined path reuses ONE fixed-block program across all three
+    block path reuses ONE 64-lane block program across all three
     matrix sizes."""
+    from repro.optimizer import scenarios
+
     cfg = ReplayConfig()
     scens = build_scenarios(ds, seeds=(0, 1, 2),
                             conditions=(HEALTHY, degraded_condition))
@@ -380,13 +404,15 @@ def test_trace_amortized_across_lane_counts(ds, machine_scores,
             np.testing.assert_array_equal(again.chosen,
                                           results[n].chosen)
 
-    # pipelined: fixed 64-lane blocks -> one program for ALL sizes
-    replay_pipelined(ds, scens[:100], machine_scores, cfg,
-                     block_lanes=64)  # warm the single block shape
+    # blocks of 64 lanes -> one program for ALL sizes
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 64)
+    replay_scenarios(ds, scens[:100], machine_scores,
+                     cfg)  # warm the single block shape
     with expect_traces(REPLAY_TRACES, 0):
         for n in (200, 432):
-            got = replay_pipelined(ds, scens[:n], machine_scores, cfg,
-                                   block_lanes=64)
+            got, split = replay_scenarios(ds, scens[:n], machine_scores,
+                                          cfg, return_result=True)
+            assert split.dispatches == -(-n // 64)
             _assert_same_traces(
                 traces_from_result(tabs[n], results[n], ds.configs),
                 got)
@@ -432,15 +458,24 @@ def test_seeded_scenarios_end_to_end(ds, machine_scores):
                             bt, sc)
 
 
-def test_seeded_pipelined_matches_unpipelined(ds, machine_scores):
+def test_seeded_pipelined_matches_unpipelined(ds, machine_scores,
+                                             monkeypatch):
+    """Across lowerings: the seeded lowering's blocks give the host
+    tables' one-dispatch traces, picks and counts lane for lane."""
+    from repro.optimizer import scenarios
+
     scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
                             seeds=(0, 1), conditions=(HEALTHY,))
-    ref = replay_scenarios(ds, scens, machine_scores)
-    got, stats = replay_pipelined(ds, scens, machine_scores,
-                                  block_lanes=8, seeded=True,
-                                  return_stats=True)
-    _assert_same_traces(ref, got)
-    assert stats["blocks"] == stats["dispatches"] == 2
+    ref, host = replay_scenarios(ds, scens, machine_scores,
+                                 return_result=True)
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
+    got, seeded = replay_scenarios(ds, scens, machine_scores,
+                                   return_result=True, seeded=True)
+    assert host.dispatches == 1 and seeded.dispatches == 2
+    for sc, a, b in zip(scens, ref, got):
+        _assert_trace_equal(a, b, sc)
+    np.testing.assert_array_equal(host.chosen, seeded.chosen)
+    np.testing.assert_array_equal(host.count, seeded.count)
 
 
 def test_seeded_replay_compile_amortized(ds, machine_scores):
@@ -466,7 +501,8 @@ def test_seeded_replay_compile_amortized(ds, machine_scores):
 def test_sharded_replay_bit_identical_subprocess():
     """8 virtual CPU devices: shard_map'd lanes must reproduce the
     single-device scanned replay bit-for-bit on the full 432-lane
-    matrix, and the pipelined sharded path must match lane-for-lane."""
+    matrix, and replay_scenarios over the mesh must match lane for lane
+    on both lowerings."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -475,8 +511,8 @@ def test_sharded_replay_bit_identical_subprocess():
         from repro.optimizer import (HEALTHY, FleetCondition,
                                      ReplayConfig, build_scenarios,
                                      lane_spec, lane_tables, replay,
-                                     replay_pipelined, replay_scenarios,
-                                     replay_seeded, traces_from_result)
+                                     replay_scenarios, replay_seeded,
+                                     traces_from_result)
         from repro.tuning.scout import ScoutDataset, VM_TYPES
 
         assert jax.device_count() == 8
@@ -505,13 +541,12 @@ def test_sharded_replay_bit_identical_subprocess():
         assert np.array_equal(single.count, seeded.count)
 
         ref = traces_from_result(tab, single, ds.configs)
-        piped = replay_pipelined(ds, scens, scores, cfg,
-                                 block_lanes=64,
-                                 devices=jax.devices())
-        piped_seeded = replay_pipelined(ds, scens, scores, cfg,
-                                        block_lanes=64, seeded=True,
-                                        devices=jax.devices())
-        for a, b, c in zip(ref, piped, piped_seeded):
+        mesh = replay_scenarios(ds, scens, scores, cfg,
+                                devices=jax.devices())
+        mesh_seeded = replay_scenarios(ds, scens, scores, cfg,
+                                       seeded=True,
+                                       devices=jax.devices())
+        for a, b, c in zip(ref, mesh, mesh_seeded):
             assert [x.key for x in a.evaluated] == \\
                 [x.key for x in b.evaluated] == \\
                 [x.key for x in c.evaluated]

@@ -1,16 +1,15 @@
 """The configuration-search path's spans: one of each per matrix and
 none per lane, whatever the matrix's width, on the host and the seeded
 lowering alike; a matrix split into lane blocks launches each block
-before it fetches the one before; under the pipelined replay the
-per-block staging, launch and wait nest in the worker's block span."""
+before it fetches the one before, and each block's spans stand at the
+top level on the calling thread."""
 
 import collections
 
 import pytest
 
 from repro import obs
-from repro.optimizer import (HEALTHY, build_scenarios, replay_pipelined,
-                             replay_scenarios)
+from repro.optimizer import HEALTHY, build_scenarios, replay_scenarios
 from repro.tuning.scout import VM_TYPES, WORKLOAD_NAMES, ScoutDataset
 
 
@@ -88,25 +87,25 @@ def test_blocks_launch_ahead_of_the_fetch(scout, monkeypatch, seeded):
     assert mat[3].ts >= wait[3].ts + wait[3].dur
 
 
-def test_pipelined_blocks_nest_their_replay_spans(scout):
+def test_pipelined_blocks_nest_their_replay_spans(scout, monkeypatch):
+    """Under the block path every per-block span is top-level, one per
+    block, all on the calling thread, with no wrapper span around a
+    block."""
+    from repro.optimizer import scenarios
+
     ds, scores = scout
+    monkeypatch.setattr(scenarios, "REPLAY_BLOCK_LANES", 8)
     tr = obs.tracer()
     tr.clear()
     scens = build_scenarios(ds, workloads=WORKLOAD_NAMES[:2],
                             seeds=(0, 1), conditions=(HEALTHY,))
-    replay_pipelined(ds, scens, scores, block_lanes=8)
+    replay_scenarios(ds, scens, scores)
     evs = tr.events()
-    blocks = {e.id: e for e in evs if e.name == "replay.block_scan"}
-    assert len(blocks) == 2
-    for name in ("replay.stage", "replay.dispatch", "replay.wait"):
-        inner = [e for e in evs if e.name == name]
-        assert len(inner) == 2
-        for e in inner:
-            assert e.parent in blocks
-            assert e.tid == blocks[e.parent].tid
-    # table building and trace materialization stay on the main thread,
-    # once per block, with no wrapper span repeating them
-    for name in ("replay.lane_tables", "replay.materialize_traces"):
+    per_block = ("replay.lane_tables", "replay.stage", "replay.dispatch",
+                 "replay.wait", "replay.materialize_traces")
+    for name in per_block:
         own = [e for e in evs if e.name == name]
         assert len(own) == 2 and all(e.parent is None for e in own)
-    assert not any(e.name == "replay.build_tables" for e in evs)
+    assert len({e.tid for e in evs}) == 1
+    # no block wrapper span of any name around them
+    assert {e.name for e in evs} == {"replay.build_scenarios", *per_block}
